@@ -14,6 +14,9 @@ def main() -> None:
                          "serving,degrees,t1t2,k_sweep,scale,kernels")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     from benchmarks import (bench_construction, bench_degrees, bench_k_sweep,
                             bench_kernels, bench_quant, bench_scale,
                             bench_search, bench_serving, bench_streaming,

@@ -29,7 +29,7 @@ Scaling out
 Both halves of the system run on a ``jax.sharding.Mesh``; results are
 *exactly equal* to single-device (tests/test_sharded_parity.py):
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))  # repro.launch.mesh
 
     # construction: graph rows shard across the mesh (core/shard.py);
     # x is replicated and each shard ships destination-bucketed
@@ -189,6 +189,7 @@ from repro.core import nsg_style
 from repro.core import rnn_descent as rd
 from repro.core import search as S
 from repro.data.synthetic import VectorDatasetSpec, clustered_vectors
+from repro.launch.mesh import make_mesh
 
 x, q = clustered_vectors(
     jax.random.PRNGKey(0),
@@ -245,7 +246,7 @@ for label, cfg in (("jnp-ref", scfg), ("pallas-fused", fused_cfg)):
 # a mesh over every visible device — bitwise-equal to the single-device runs
 import numpy as np
 
-mesh = jax.make_mesh((jax.device_count(),), ("data",))
+mesh = make_mesh((jax.device_count(),), ("data",))
 rnnd_cfg = rd.RNNDescentConfig(s=12, r=48, t1=4, t2=6, capacity=64)
 g_shard = jax.block_until_ready(
     rd.build(x, rnnd_cfg, jax.random.PRNGKey(1), mesh=mesh))

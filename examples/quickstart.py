@@ -12,6 +12,7 @@ from repro.core import eval as E
 from repro.core import rnn_descent as rd
 from repro.core import search as S
 from repro.data.synthetic import VectorDatasetSpec, clustered_vectors
+from repro.launch.mesh import make_mesh
 
 # 1. a corpus (SIFT-like dims at laptop scale) + queries + exact ground truth
 x, queries = clustered_vectors(
@@ -54,7 +55,7 @@ print(f"  fused beam kernel: recall@1={E.recall_at_k(ids_f, gt):.4f} "
 # search_tiled(..., mesh=mesh) shards query tiles. See the "Scaling out"
 # section in examples/build_and_search.py; on CPU forge devices with
 # XLA_FLAGS=--xla_force_host_platform_device_count=8.
-mesh = jax.make_mesh((jax.device_count(),), ("data",))
+mesh = make_mesh((jax.device_count(),), ("data",))
 scfg = S.SearchConfig(l=32, k=32, max_iters=96)
 ids_m, _ = S.search_tiled(x, graph, queries, entry, scfg, tile_b=128, mesh=mesh)
 print(f"  sharded serving ({jax.device_count()} device(s)): "

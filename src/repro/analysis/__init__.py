@@ -10,7 +10,7 @@ pass       what it proves
 ========== =============================================================
 jaxpr      Abstract-traces every registered public entry point
            (:mod:`repro.analysis.registry`) and walks the jaxpr — incl.
-           all scan/while/pjit/shard_map/pallas_call sub-jaxprs — for
+           all scan/while/jit/shard_map/pallas_call sub-jaxprs — for
            f64/weak-type leaks, implicit upcasts and accumulator
            violations in distance dots, non-ordinal arithmetic on uint32
            dist keys (taint analysis from the ``dist_key`` bitcast),

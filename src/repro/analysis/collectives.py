@@ -51,7 +51,8 @@ def sharded_build_hlo(n: int = 64, d: int = 8, mesh=None) -> tuple[str, dict]:
     from repro.core import rnn_descent as rd
 
     if mesh is None:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((jax.device_count(),), ("data",))
     cfg = rd.RNNDescentConfig(s=4, r=8, t1=2, t2=2, capacity=16, chunk=32)
     fn = jax.jit(lambda x, k: rd.build(x, cfg, k, mesh=mesh))
     args = (jax.ShapeDtypeStruct((n, d), jnp.float32), jax.random.PRNGKey(0))
@@ -84,7 +85,8 @@ def corpus_serving_hlo(n: int = 4096, d: int = 32, b: int = 8,
     from repro.core import search as S
 
     if mesh is None:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((jax.device_count(),), ("data",))
     cfg = S.SearchConfig(l=8, k=8, max_iters=8, topk=4)
     cap = 16
     g = G.Graph(neighbors=jax.ShapeDtypeStruct((n, cap), jnp.int32),

@@ -1,5 +1,5 @@
 """Jaxpr invariant auditor: abstract-trace every public entry point and walk
-the closed jaxpr (recursing into scan/while/cond/pjit/shard_map/pallas_call
+the closed jaxpr (recursing into scan/while/cond/jit/shard_map/pallas_call
 sub-jaxprs) for dtype and semantics invariants the test suite can't see —
 a leak only costs recall/memory at production scale, not correctness at
 test scale.
@@ -31,7 +31,7 @@ Rules (each one finding per (entry, primitive) site):
     only comparisons, bitwise ops, min/max-style selection, sorting and
     data movement are meaningful. Arithmetic (add/mul/dot/float converts)
     on a key silently destroys the monotone order contract. Taint is
-    propagated *through* call-style sub-jaxprs (``pjit``/``remat`` — the
+    propagated *through* call-style sub-jaxprs (``jit``/``remat`` — the
     wrappers jnp helpers like ``jnp.where`` insert) by positional argument
     mapping, but dropped at loop/branch boundaries (``scan``/``while``/
     ``cond`` carry structure): a key carried through a ``scan`` re-taints
@@ -57,10 +57,7 @@ import jax
 
 from repro.analysis.baseline import Finding
 
-try:  # jax >= 0.4.30 public core aliases
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as jcore  # type: ignore
+from jax.extend import core as jcore
 
 _WIDE = {"float64", "complex128", "int64", "uint64"}
 _LOWP = {"bfloat16", "float16"}
@@ -89,13 +86,13 @@ _TAINT_SINK = {"eq", "ne", "lt", "le", "gt", "ge", "argmin", "argmax",
 # call-style primitives: one sub-jaxpr whose invars map positionally onto the
 # equation's invars, so key taint threads straight through (jnp helpers like
 # jnp.where / jnp.clip arrive wrapped in one of these).
-_CALL_PRIMS = {"pjit", "closed_call", "core_call", "remat", "checkpoint",
+_CALL_PRIMS = {"jit", "closed_call", "core_call", "remat", "checkpoint",
                "custom_jvp_call", "custom_vjp_call", "remat2"}
 
 
 def iter_jaxprs(closed) -> Iterable:
     """Yield a jaxpr and, depth-first, every sub-jaxpr reachable through
-    equation params (scan/while/cond bodies, pjit/shard_map/pallas_call
+    equation params (scan/while/cond bodies, jit/shard_map/pallas_call
     callees, custom_*_call rules) — whatever the param structure."""
     root = closed.jaxpr if hasattr(closed, "jaxpr") else closed
     stack = [root]
@@ -131,7 +128,7 @@ def _audit_rec(entry: str, jaxpr,
                taint_in: list[bool]) -> tuple[list[Finding], list[bool]]:
     """Audit ``jaxpr`` with ``taint_in`` marking which invars hold uint32
     dist keys; returns (findings, per-outvar taint) so call-style sub-jaxprs
-    (pjit/remat) thread taint through positionally."""
+    (jit/remat) thread taint through positionally."""
     findings: list[Finding] = []
     tainted: set = set()   # Vars holding uint32 dist keys (this jaxpr)
     for v, t in zip(jaxpr.invars, taint_in):

@@ -28,7 +28,8 @@ N, D, M, B = 32, 8, 16, 4     # corpus rows/dims, adjacency cap, query batch
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1,), ("data",))
 
 
 def _x():

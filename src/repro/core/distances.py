@@ -14,6 +14,16 @@ import jax.numpy as jnp
 
 Metric = str  # "l2" (squared), "ip" (negative inner product), "cos"
 
+# Every distance dot runs at full f32 precision. The TPU default for an f32
+# matmul rounds its inputs to bfloat16, which at ||a||^2 + ||b||^2 - 2ab
+# cancels most of the distance between near neighbours; the CPU ignores
+# the setting, so CPU results are unchanged.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return jnp.matmul(a, b, precision=HIGHEST)
+
 
 def _sqnorm(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(x * x, axis=-1)
@@ -23,14 +33,14 @@ def pairwise(a: jnp.ndarray, b: jnp.ndarray, metric: Metric = "l2") -> jnp.ndarr
     """Dense (na, nb) distance matrix. Smaller is closer for every metric."""
     if metric == "l2":
         # max(., 0) guards tiny negative values from cancellation.
-        d = _sqnorm(a)[:, None] + _sqnorm(b)[None, :] - 2.0 * (a @ b.T)
+        d = _sqnorm(a)[:, None] + _sqnorm(b)[None, :] - 2.0 * _mm(a, b.T)
         return jnp.maximum(d, 0.0)
     if metric == "ip":
-        return -(a @ b.T)
+        return -_mm(a, b.T)
     if metric == "cos":
         an = a / jnp.maximum(jnp.linalg.norm(a, axis=-1, keepdims=True), 1e-12)
         bn = b / jnp.maximum(jnp.linalg.norm(b, axis=-1, keepdims=True), 1e-12)
-        return 1.0 - an @ bn.T
+        return 1.0 - _mm(an, bn.T)
     raise ValueError(f"unknown metric {metric!r}")
 
 
@@ -50,13 +60,15 @@ def batched_gram(vecs: jnp.ndarray, metric: Metric = "l2") -> jnp.ndarray:
         # gather/Gram HBM traffic; the MXU accumulates f32 natively)
         sq = jnp.sum(jnp.square(vecs), axis=-1, dtype=jnp.float32)
         g = jnp.einsum("...md,...nd->...mn", vecs, vecs,
-                       preferred_element_type=jnp.float32)
+                       preferred_element_type=jnp.float32, precision=HIGHEST)
         return jnp.maximum(sq[..., :, None] + sq[..., None, :] - 2.0 * g, 0.0)
     if metric == "ip":
-        return -jnp.einsum("...md,...nd->...mn", vecs, vecs)
+        return -jnp.einsum("...md,...nd->...mn", vecs, vecs,
+                           precision=HIGHEST)
     if metric == "cos":
         n = vecs / jnp.maximum(jnp.linalg.norm(vecs, axis=-1, keepdims=True), 1e-12)
-        return 1.0 - jnp.einsum("...md,...nd->...mn", n, n)
+        return 1.0 - jnp.einsum("...md,...nd->...mn", n, n,
+                                precision=HIGHEST)
     raise ValueError(f"unknown metric {metric!r}")
 
 
@@ -92,9 +104,15 @@ def pairwise_tiled(
     return d.reshape(-1, k)[:na], idx.reshape(-1, k)[:na]
 
 
-@functools.partial(jax.jit, static_argnames=("metric",))
-def gather_dists(x: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray, metric: Metric = "l2") -> jnp.ndarray:
-    """Distances between row pairs (x[u[i]], x[v[i]]). Invalid (-1) ids -> +inf."""
+# Pair block of gather_dists: the gathered rows of one block take
+# 2 * 2^18 * d * 4 bytes (256 MiB at d=128 f32), whatever the pair count.
+# The whole-array gather of RandomGraph(S=20) at 1M rows would hold two
+# (20M, 128) f32 copies of x (19 GB), more than a 16 GB chip.
+GATHER_BLOCK = 1 << 18
+
+
+def _pair_dists(x: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray,
+                metric: Metric) -> jnp.ndarray:
     xu = x[jnp.maximum(u, 0)]
     xv = x[jnp.maximum(v, 0)]
     if metric == "l2":
@@ -109,3 +127,19 @@ def gather_dists(x: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray, metric: Metric 
     else:
         raise ValueError(f"unknown metric {metric!r}")
     return jnp.where((u < 0) | (v < 0), jnp.inf, d)
+
+
+@functools.partial(jax.jit, static_argnames=("metric",))
+def gather_dists(x: jnp.ndarray, u: jnp.ndarray, v: jnp.ndarray, metric: Metric = "l2") -> jnp.ndarray:
+    """Distances between row pairs (x[u[i]], x[v[i]]). Invalid (-1) ids -> +inf.
+
+    More than ``GATHER_BLOCK`` pairs are scored block by block (each pair's
+    distance is computed alone, so the blocking changes no result)."""
+    p = u.shape[0]
+    if p <= GATHER_BLOCK:
+        return _pair_dists(x, u, v, metric)
+    pad = (-p) % GATHER_BLOCK
+    blocks = tuple(jnp.pad(a, (0, pad), constant_values=-1)
+                   .reshape(-1, GATHER_BLOCK) for a in (u, v))
+    d = jax.lax.map(lambda uv: _pair_dists(x, *uv, metric), blocks)
+    return d.reshape(-1)[:p]

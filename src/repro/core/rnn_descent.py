@@ -145,11 +145,15 @@ def prune_rows(
         one_chunk,
         (ids.reshape(-1, chunk, m), dists.reshape(-1, chunk, m), flags.reshape(-1, chunk, m)),
     )
-    return (
+    # The barrier stops XLA from fusing a caller's flatten of these outputs
+    # (the sweep's candidate lists) into the map's stacked (rows/chunk,
+    # chunk, m) layout: that fusion leaves a relayout of a packed bool mask
+    # to an (n*m, 1) column whose TPU code generation time grows with n.
+    return jax.lax.optimization_barrier((
         keep.reshape(-1, m)[:n_rows],
         red_w.reshape(-1, m)[:n_rows],
         red_d.reshape(-1, m)[:n_rows],
-    )
+    ))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

@@ -655,6 +655,9 @@ def _search_tiled_jit(
             f"unknown shard mode {shard!r}: expected \"queries\" (tiles "
             "shard, corpus replicated) or \"corpus\" (rows shard, queries "
             "tile through collectives)")
+    if mesh is not None:
+        from repro.launch.mesh import check_auto
+        check_auto(mesh)
     b = queries.shape[0]
     eps = _validate_entry_points(entry_points, b, cfg.l)
     if lane_valid is not None and lane_valid.shape != (b,):
@@ -711,7 +714,6 @@ def _search_tiled_jit(
         # taken whenever the mesh routes a "queries" axis — including a
         # 1-wide mesh, so single-device runs still exercise the real
         # shard_map dispatch (the 1-device CI smoke relies on this)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         qspec = SH.pspec(mesh, "queries", None, None)
         rep = G.Graph(P(), P(), P())
@@ -739,12 +741,12 @@ def _search_tiled_jit(
             return tiles_body(xx, gg, vv, qq, rest[i], rest[i + 1],
                               rest[i + 2])
 
-        ids, dists, lane_work, tile_iters = shard_map(
+        ids, dists, lane_work, tile_iters = jax.shard_map(
             dispatch, mesh=mesh,
             in_specs=tuple(specs),
             out_specs=(qspec, qspec, SH.pspec(mesh, "queries", None),
                        SH.pspec(mesh, "queries")),
-            check_rep=False,
+            check_vma=False,
         )(*operands)
     else:
         ids, dists, lane_work, tile_iters = tiles_body(

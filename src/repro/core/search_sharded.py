@@ -55,7 +55,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import graph as G
@@ -303,10 +302,10 @@ def search_tiled_corpus(x, g, queries, eps, cfg, tile_b, mesh,
                                jnp.arange(t_count))
         return outs   # ids (T, tile_b, topk), dists, work (T, tile_b), (T,)
 
-    ids, dists, lane_work, tile_iters = shard_map(
+    ids, dists, lane_work, tile_iters = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=tuple(specs),
         out_specs=(lane3, lane3, lane2, P()),
-        check_rep=False,
+        check_vma=False,
     )(*operands)
     out = (ids.reshape(-1, cfg.topk)[:b], dists.reshape(-1, cfg.topk)[:b])
     if not with_stats:

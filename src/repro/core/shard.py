@@ -63,7 +63,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import graph as G
@@ -95,6 +94,8 @@ def _graph_specs(mesh: Mesh) -> G.Graph:
 
 
 def _check_mesh(mesh: Mesh, merge: str) -> None:
+    from repro.launch.mesh import check_auto
+    check_auto(mesh)
     if merge != "bucketed":
         raise ValueError(
             f"sharded builds require merge='bucketed' (got {merge!r}): the "
@@ -225,10 +226,10 @@ def merge_candidate_edges(g: G.Graph, cand_src, cand_dst, cand_dist,
     def shard_fn(gl, cs, cd, cw):
         return _merge_candidates_shard(gl, cs, cd, cw, n_pad, cap, b, axes, d)
 
-    gs = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(_graph_specs(mesh), P(), P(), P()),
-                   out_specs=_graph_specs(mesh),
-                   check_rep=False)(
+    gs = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_graph_specs(mesh), P(), P(), P()),
+                       out_specs=_graph_specs(mesh),
+                       check_vma=False)(
         pad_rows(g, n_pad), cand_src.reshape(-1), cand_dst.reshape(-1),
         cand_dist.reshape(-1))
     return G.Graph(gs.neighbors[:n], gs.dists[:n], gs.flags[:n])
@@ -274,10 +275,10 @@ def rnn_update_neighbors(x, g: G.Graph, cfg, mesh: Mesh, qx=None) -> G.Graph:
     if has_qx:
         operands.append(qx)
         specs.append(jax.tree.map(lambda _: P(), qx))
-    gs = shard_map(shard_fn, mesh=mesh,
-                   in_specs=tuple(specs),
-                   out_specs=_graph_specs(mesh),
-                   check_rep=False)(*operands)
+    gs = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=tuple(specs),
+                       out_specs=_graph_specs(mesh),
+                       check_vma=False)(*operands)
     return G.Graph(gs.neighbors[:n], gs.dists[:n], gs.flags[:n])
 
 
@@ -340,10 +341,10 @@ def add_reverse_edges(g: G.Graph, r: int, mesh: Mesh,
         return G.Graph(*G.row_topk(o_ids, o_dist, o_flag, min(r, m), m))
 
     row_ids = jnp.arange(n_pad, dtype=jnp.int32)
-    gs = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(_graph_specs(mesh), _row1_pspec(mesh)),
-                   out_specs=_graph_specs(mesh),
-                   check_rep=False)(pad_rows(g, n_pad), row_ids)
+    gs = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(_graph_specs(mesh), _row1_pspec(mesh)),
+                       out_specs=_graph_specs(mesh),
+                       check_vma=False)(pad_rows(g, n_pad), row_ids)
     return G.Graph(gs.neighbors[:n], gs.dists[:n], gs.flags[:n])
 
 
@@ -433,10 +434,10 @@ def nn_join_and_update(x, g: G.Graph, cfg, mesh: Mesh) -> G.Graph:
         return _merge_candidates_shard(
             aged, src, dst, dist, n_pad, cfg.k, nb, axes, d)
 
-    gs = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(), _graph_specs(mesh)),
-                   out_specs=_graph_specs(mesh),
-                   check_rep=False)(x, pad_rows(g, n_pad))
+    gs = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(), _graph_specs(mesh)),
+                       out_specs=_graph_specs(mesh),
+                       check_vma=False)(x, pad_rows(g, n_pad))
     return G.Graph(gs.neighbors[:n], gs.dists[:n], gs.flags[:n])
 
 
@@ -482,10 +483,10 @@ def _nsg_expand_cap(x, knn: G.Graph, cfg, mesh: Mesh) -> G.Graph:
         return nsg_style.rng_cap_rows(xx, cand_ids, cand_d, cfg)
 
     rep = G.Graph(P(), P(), P())
-    gs = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(), rep, _row1_pspec(mesh)),
-                   out_specs=_graph_specs(mesh),
-                   check_rep=False)(x, knn, rows)
+    gs = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(), rep, _row1_pspec(mesh)),
+                       out_specs=_graph_specs(mesh),
+                       check_vma=False)(x, knn, rows)
     return G.Graph(gs.neighbors[:n], gs.dists[:n], gs.flags[:n])
 
 

@@ -13,7 +13,23 @@ is unchanged and only the stored-vector precision differs.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+# full-f32 dots (the TPU default rounds f32 matmul inputs to bfloat16;
+# core/distances.py says why that breaks near-neighbour distances)
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _sqsum(a: jnp.ndarray) -> jnp.ndarray:
+    """(..., d) -> (...) squared norms as one batched dot. Each row is its
+    own (1, d) x (1, d) batch entry: Mosaic lowers no dot without a
+    non-contracting dim on each side, nor one with more than one batch
+    dim, so the batch dims are flattened into one."""
+    rows = a.reshape(-1, 1, a.shape[-1])
+    return jnp.einsum("nxd,nyd->nxy", rows, rows,
+                      preferred_element_type=jnp.float32,
+                      precision=HIGHEST).reshape(a.shape[:-1])
 
 
 def score_block(vecs: jnp.ndarray, q: jnp.ndarray, metric: str) -> jnp.ndarray:
@@ -26,20 +42,22 @@ def score_block(vecs: jnp.ndarray, q: jnp.ndarray, metric: str) -> jnp.ndarray:
     # order fixed across fusion contexts, where a fused jnp.sum(v*v) does
     # not — and the Pallas-interpret and pure-jnp paths must agree bitwise
     # (asserted in tests/test_beam_score.py), not just to tolerance.
-    sqsum = lambda a: jnp.einsum("...d,...d->...", a, a,
-                                 preferred_element_type=jnp.float32)
+    sqsum = _sqsum
     if metric == "l2":
         dot = jnp.einsum("...kd,...d->...k", v, qq,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=HIGHEST)
         return jnp.maximum(sqsum(qq)[..., None] + sqsum(v) - 2.0 * dot, 0.0)
     if metric == "ip":
         return -jnp.einsum("...kd,...d->...k", v, qq,
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32,
+                           precision=HIGHEST)
     if metric == "cos":
         vn = v / jnp.maximum(jnp.sqrt(sqsum(v))[..., None], 1e-12)
         qn = qq / jnp.maximum(jnp.sqrt(sqsum(qq))[..., None], 1e-12)
         return 1.0 - jnp.einsum("...kd,...d->...k", vn, qn,
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=HIGHEST)
     raise ValueError(f"unknown metric {metric!r}")
 
 

@@ -26,7 +26,8 @@ def _pairwise_l2_body(a_ref, b_ref, o_ref):
     an = jnp.sum(a * a, axis=-1, keepdims=True)  # (tm, 1)
     bn = jnp.sum(b * b, axis=-1, keepdims=True)  # (tn, 1)
     dot = jax.lax.dot_general(                   # (tm, tn) on the MXU
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     o_ref[...] = jnp.maximum(an + bn.T - 2.0 * dot, 0.0)
 

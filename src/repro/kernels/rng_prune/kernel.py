@@ -7,8 +7,8 @@ is the TPU-native rethink of the paper's per-pair scalar distance evaluations:
 the CPU code's early-exit saves distance computations; on TPU distances are
 effectively free on the MXU and the win is avoiding HBM traffic for the Gram.
 
-VMEM budget per tile (fp32): tc=8, M=128, d=960 -> vecs 3.9 MiB + gram
-0.5 MiB + scan state << 16 MiB.
+VMEM budget per tile (fp32): tc=8, M=128, d=960 -> vecs 3.9 MiB (7.9 MiB
+double-buffered) + pair scratch 0.5 MiB + scan state < 16 MiB.
 
 The neighbor gather itself stays outside the kernel (XLA's native gather is
 already bandwidth-optimal on TPU for row gathers; Pallas adds nothing there).
@@ -20,57 +20,85 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _prune_scan(ids, dists, flags, vecs):
+def _prune_scan(ids, dists, flags, vecs, pair_ref):
     """Shared Gram + keep/redirect scan over an f32 (tc, M, d) candidate
     block — the body tail for both the f32/bf16 and the int8-decode
-    variants (int8 only changes how ``vecs`` got into registers)."""
+    variants (int8 only changes how ``vecs`` got into registers).
+
+    Written for Mosaic: the candidate-pair block goes to the VMEM scratch
+    ``pair_ref`` (tc, M, M) and the scan reads row i with a ref load; every
+    per-column read, write and gather along the candidate (lane) axis is a
+    one-hot ``iota`` select reduced by max/min, which picks one element
+    exactly. ``flags`` and the returned ``keep`` are int32 (an 8-row 8-bit
+    block would sit below the 8-bit (32, 128) tiling)."""
     tc, m = ids.shape
-    sq = jnp.sum(vecs * vecs, axis=-1)                  # (tc, M)
+    sq = jnp.broadcast_to(jnp.sum(vecs * vecs, axis=-1, keepdims=True),
+                          (tc, m, m))                    # [c, i, j] = |v_i|^2
     gram = jax.lax.dot_general(                          # (tc, M, M) on the MXU
-        vecs, vecs, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        vecs, vecs, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
     )
-    pair = jnp.maximum(sq[:, :, None] + sq[:, None, :] - 2.0 * gram, 0.0)
+    pair = jnp.maximum(sq + jnp.swapaxes(sq, 1, 2) - 2.0 * gram, 0.0)
     valid = ids >= 0
+
+    def both(mask):                                      # mask_i & mask_j
+        v = mask.astype(jnp.int32)
+        return (v[:, :, None] * v[:, None, :]) > 0
+
     big = jnp.float32(3.4e38)                           # +inf stand-in (VMEM-safe)
-    pair = jnp.where(valid[:, :, None] & valid[:, None, :], pair, big)
-    old = flags == 0
-    skip = old[:, :, None] & old[:, None, :]            # old-old pairs exempt
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tc,), 0)
+    pair = jnp.where(both(valid), pair, big)
+    # old-old pairs are exempt: NaN never satisfies `<=`, so an exempt pair
+    # can never fail (and a failing pair, the only kind read back, is real)
+    pair_ref[...] = jnp.where(both(flags == 0), jnp.nan, pair)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tc, m), 1)
+    valid_i32 = valid.astype(jnp.int32)
+    i32_min = jnp.int32(jnp.iinfo(jnp.int32).min)
+
+    def pick(onehot, v, fill):
+        return jnp.max(jnp.where(onehot, v, fill), axis=1, keepdims=True)
 
     def body(i, carry):
         keep, red_w, red_d = carry
-        fail = keep & (~skip[:, i, :]) & (pair[:, i, :] <= dists[:, i][:, None])
-        any_fail = jnp.any(fail, axis=1) & valid[:, i]
-        first_j = jnp.argmax(fail, axis=1)
-        keep = keep.at[:, i].set(valid[:, i] & ~any_fail)
-        red_w = red_w.at[:, i].set(jnp.where(any_fail, ids[rows, first_j], jnp.int32(-1)))
-        red_d = red_d.at[:, i].set(jnp.where(any_fail, pair[rows, i, first_j], big))
+        row = pair_ref[:, i, :]                          # pair[:, i, :]
+        col_i = lane == i
+        d_i = pick(col_i, dists, -jnp.inf)               # dists[:, i]
+        valid_i = pick(col_i, valid_i32, 0) > 0          # valid[:, i]
+        fail = (keep > 0) & (row <= d_i)
+        any_fail = (jnp.max(fail.astype(jnp.int32), axis=1, keepdims=True)
+                    > 0) & valid_i
+        first_j = jnp.min(jnp.where(fail, lane, m), axis=1, keepdims=True)
+        at_j = lane == first_j
+        w = jnp.where(any_fail, pick(at_j, ids, i32_min), jnp.int32(-1))
+        dw = jnp.where(any_fail, pick(at_j, row, -jnp.inf), big)
+        keep = jnp.where(col_i, (valid_i & ~any_fail).astype(jnp.int32), keep)
+        red_w = jnp.where(col_i, w, red_w)
+        red_d = jnp.where(col_i, dw, red_d)
         return keep, red_w, red_d
 
     init = (
-        jnp.zeros((tc, m), bool),
+        jnp.zeros((tc, m), jnp.int32),
         jnp.full((tc, m), -1, jnp.int32),
         jnp.full((tc, m), big, jnp.float32),
     )
     keep, red_w, red_d = jax.lax.fori_loop(0, m, body, init)
-    return keep.astype(jnp.uint8), red_w, jnp.where(red_d >= big, jnp.inf,
-                                                    red_d)
+    return keep, red_w, jnp.where(red_d >= big, jnp.inf, red_d)
 
 
 def _rng_prune_body(ids_ref, dists_ref, flags_ref, vecs_ref, keep_ref,
-                    redw_ref, redd_ref):
+                    redw_ref, redd_ref, pair_ref):
     vecs = vecs_ref[...].astype(jnp.float32)            # (tc, M, d)
     keep, red_w, red_d = _prune_scan(ids_ref[...], dists_ref[...],
-                                     flags_ref[...], vecs)
+                                     flags_ref[...], vecs, pair_ref)
     keep_ref[...] = keep
     redw_ref[...] = red_w
     redd_ref[...] = red_d
 
 
 def _rng_prune_int8_body(ids_ref, dists_ref, flags_ref, codes_ref, scale_ref,
-                         zero_ref, keep_ref, redw_ref, redd_ref):
+                         zero_ref, keep_ref, redw_ref, redd_ref, pair_ref):
     """int8 variant: the gathered candidate block arrives as (tc, M, d)
     int8 codes (4x less HBM->VMEM traffic) and dequantizes in-register via
     the shared ``repro.quant.int8_decode`` before the same Gram + scan.
@@ -78,9 +106,9 @@ def _rng_prune_int8_body(ids_ref, dists_ref, flags_ref, codes_ref, scale_ref,
     the oracle's gather-after-decode."""
     from repro.quant import int8_decode
 
-    vecs = int8_decode(codes_ref[...], scale_ref[0], zero_ref[0])
+    vecs = int8_decode(codes_ref[...], scale_ref[...], zero_ref[...])
     keep, red_w, red_d = _prune_scan(ids_ref[...], dists_ref[...],
-                                     flags_ref[...], vecs)
+                                     flags_ref[...], vecs, pair_ref)
     keep_ref[...] = keep
     redw_ref[...] = red_w
     redd_ref[...] = red_d
@@ -150,10 +178,11 @@ def rng_prune_int8_tiles(
         in_specs=[pl.BlockSpec(bs, im) for _, bs, im in ins],
         out_specs=[pl.BlockSpec(bs, im) for _, bs, im in outs],
         out_shape=[
-            jax.ShapeDtypeStruct((n, m), jnp.uint8),
+            jax.ShapeDtypeStruct((n, m), jnp.int32),
             jax.ShapeDtypeStruct((n, m), jnp.int32),
             jax.ShapeDtypeStruct((n, m), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((tile_c, m, m), jnp.float32)],
         interpret=interpret,
     )(ids, dists, flags, codes, scale, zero)
 
@@ -181,9 +210,10 @@ def rng_prune_tiles(
         in_specs=[pl.BlockSpec(bs, im) for _, bs, im in ins],
         out_specs=[pl.BlockSpec(bs, im) for _, bs, im in outs],
         out_shape=[
-            jax.ShapeDtypeStruct((n, m), jnp.uint8),
+            jax.ShapeDtypeStruct((n, m), jnp.int32),
             jax.ShapeDtypeStruct((n, m), jnp.int32),
             jax.ShapeDtypeStruct((n, m), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((tile_c, m, m), jnp.float32)],
         interpret=interpret,
     )(ids, dists, flags, vecs)

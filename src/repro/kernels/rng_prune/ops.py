@@ -36,8 +36,8 @@ def rng_prune(
     if interpret is None:
         interpret = default_interpret()
     n, m = ids.shape
-    if flags is None:
-        flags = jnp.ones((n, m), jnp.uint8)
+    flags = (jnp.ones((n, m), jnp.int32) if flags is None
+             else flags.astype(jnp.int32))
     if gram_dtype == "bf16":
         x = x.astype(jnp.bfloat16)
     pad = (-n) % tile_c
@@ -70,8 +70,8 @@ def rng_prune_int8(
     if interpret is None:
         interpret = default_interpret()
     n, m = ids.shape
-    if flags is None:
-        flags = jnp.ones((n, m), jnp.uint8)
+    flags = (jnp.ones((n, m), jnp.int32) if flags is None
+             else flags.astype(jnp.int32))
     pad = (-n) % tile_c
     ids_p = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
     dists_p = jnp.pad(dists, ((0, pad), (0, 0)), constant_values=jnp.inf)
@@ -97,9 +97,9 @@ def kernel_spec(*, n: int = 64, m: int = 32, d: int = 64, tile_c: int = 8,
     shapes = {
         "ids": ((n, m), jnp.int32),
         "dists": ((n, m), jnp.float32),
-        "flags": ((n, m), jnp.uint8),
+        "flags": ((n, m), jnp.int32),
         "vecs": ((n, m, d), vdt),
-        "keep": ((n, m), jnp.uint8),
+        "keep": ((n, m), jnp.int32),
         "red_w": ((n, m), jnp.int32),
         "red_d": ((n, m), jnp.float32),
     }
@@ -135,11 +135,11 @@ def kernel_spec_int8(*, n: int = 64, m: int = 128, d: int = 960,
     shapes = {
         "ids": ((n, m), jnp.int32),
         "dists": ((n, m), jnp.float32),
-        "flags": ((n, m), jnp.uint8),
+        "flags": ((n, m), jnp.int32),
         "codes": ((n, m, d), jnp.int8),
         "scale": ((1, d), jnp.float32),
         "zero": ((1, d), jnp.float32),
-        "keep": ((n, m), jnp.uint8),
+        "keep": ((n, m), jnp.int32),
         "red_w": ((n, m), jnp.int32),
         "red_d": ((n, m), jnp.float32),
     }

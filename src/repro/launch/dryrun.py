@@ -3,6 +3,10 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
+# the dry-run compiles on forged host devices, and its per-cell children
+# inherit this: none of them may take an accelerator from the process
+# that owns it
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede every other import (jax locks device count on first init).
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the

@@ -34,7 +34,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.beam_score.ref import score_block
+from repro.kernels.beam_score.ref import HIGHEST, score_block
 
 MODES = ("f32", "bf16", "int8", "pq")
 
@@ -168,9 +168,11 @@ def train_pq(x: jnp.ndarray, m: int, iters: int = 8,
         # (n, dsub) x (256, dsub) -> (n,) argmin over squared distance;
         # ||data||^2 is constant per point and dropped from the argmin.
         dot = jnp.einsum("nd,cd->nc", data, cent,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=HIGHEST)
         csq = jnp.einsum("cd,cd->c", cent, cent,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=HIGHEST)
         return jnp.argmin(csq[None, :] - 2.0 * dot, axis=1)
 
     def lloyd_step(_, cent):
@@ -180,7 +182,8 @@ def train_pq(x: jnp.ndarray, m: int, iters: int = 8,
                 jnp.float32)                            # (n, 256)
             counts = jnp.sum(onehot, axis=0)            # (256,)
             sums = jnp.einsum("nc,nd->cd", onehot, data,
-                              preferred_element_type=jnp.float32)
+                              preferred_element_type=jnp.float32,
+                              precision=HIGHEST)
             return jnp.where(counts[:, None] > 0,
                              sums / jnp.maximum(counts[:, None], 1.0), c)
         return jax.vmap(one)(xs, cent)
@@ -195,9 +198,11 @@ def encode_pq_rows(x: jnp.ndarray, codebooks: jnp.ndarray) -> jnp.ndarray:
     xs = x.astype(jnp.float32).reshape(n, m, dsub)
     cb = codebooks.astype(jnp.float32)
     dot = jnp.einsum("nmd,mcd->nmc", xs, cb,
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32,
+                     precision=HIGHEST)
     csq = jnp.einsum("mcd,mcd->mc", cb, cb,
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32,
+                     precision=HIGHEST)
     return jnp.argmin(csq[None] - 2.0 * dot, axis=2).astype(jnp.uint8)
 
 
@@ -324,19 +329,23 @@ def pq_lut(queries: jnp.ndarray, codebooks: jnp.ndarray, metric: str
     qs = qf.reshape(bsz, m, dsub)
     cb = codebooks.astype(jnp.float32)
     dot = jnp.einsum("bmd,mcd->bmc", qs, cb,
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32,
+                     precision=HIGHEST)
     csq = jnp.einsum("mcd,mcd->mc", cb, cb,
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32,
+                     precision=HIGHEST)
     if metric == "l2":
         qsq_s = jnp.einsum("bmd,bmd->bm", qs, qs,
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32,
+                           precision=HIGHEST)
         lut_a = jnp.maximum(qsq_s[..., None] + csq[None] - 2.0 * dot, 0.0)
         return lut_a, jnp.zeros_like(csq), jnp.zeros((bsz,), jnp.float32)
     if metric == "ip":
         return -dot, jnp.zeros_like(csq), jnp.zeros((bsz,), jnp.float32)
     if metric == "cos":
         qsq = jnp.einsum("bd,bd->b", qf, qf,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=HIGHEST)
         return dot, csq, qsq
     raise ValueError(f"unknown metric {metric!r}")
 

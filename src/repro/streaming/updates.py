@@ -218,7 +218,6 @@ def _sweep(x, g, frontier, ex_rows, ex_ids, ex_d, cfg: StreamingConfig,
         blk = _frontier_sweep_block(x, g, frontier, frontier, ex_rows, ex_ids,
                                     ex_d, cfg, (), 1, f_pad, n_buckets)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.distributed import sharding as SH
@@ -233,11 +232,11 @@ def _sweep(x, g, frontier, ex_rows, ex_ids, ex_d, cfg: StreamingConfig,
             return _frontier_sweep_block(xx, gg, fs, ff, er, ei, ed, cfg,
                                          axes, n_dev, f_pad, n_buckets)
 
-        blk = shard_map(
+        blk = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), rep, fspec, P(), P(), P(), P()),
             out_specs=G.Graph(gspec, gspec, gspec),
-            check_rep=False,
+            check_vma=False,
         )(x, g, frontier, frontier, ex_rows, ex_ids, ex_d)
     return _scatter_rows(g, frontier, blk)
 
@@ -395,7 +394,6 @@ def _repair(x, g: G.Graph, tomb, a_idx, cfg: StreamingConfig,
     if mesh is None:
         blk = _repair_block(x, g, tomb, a_idx, cfg)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.distributed import sharding as SH
@@ -407,11 +405,11 @@ def _repair(x, g: G.Graph, tomb, a_idx, cfg: StreamingConfig,
         def body(xx, gg, tt, aa):
             return _repair_block(xx, gg, tt, aa, cfg)
 
-        blk = shard_map(
+        blk = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), rep, P(), fspec),
             out_specs=G.Graph(gspec, gspec, gspec),
-            check_rep=False,
+            check_vma=False,
         )(x, g, tomb, a_idx)
     return _scatter_rows(g, a_idx, blk)
 

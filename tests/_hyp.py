@@ -9,7 +9,7 @@ of ``pytest.importorskip("hypothesis")``, which would skip whole modules and
 hide their plain unit tests).
 """
 try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
+    from hypothesis import example, given, settings, strategies as st  # noqa: F401
 
     HAVE_HYPOTHESIS = True
 except ModuleNotFoundError:
@@ -30,6 +30,8 @@ except ModuleNotFoundError:
 
     def settings(*_args, **_kwargs):
         return lambda fn: fn
+
+    example = settings
 
     class _Strategies:
         def __getattr__(self, _name):

@@ -49,7 +49,7 @@ class TestJaxprAudit:
         # the exact deployment bug: library code is traced under an
         # x64-enabled host process and a np.float64 scalar promotes the
         # whole chain to f64
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             found = _audit(lambda x: x * np.float64(2.0), _f32(4))
         assert "wide-dtype" in _rules(found)
 

@@ -249,3 +249,22 @@ def test_property_bucketed_reverse_caps(n, m, r, n_buckets, seed):
     if n_buckets >= n:
         oracle = G.add_reverse_edges(g, r, merge="sort")
         assert _canon(oracle) == _canon(out)
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip", "cos"])
+def test_gather_dists_blocks_match_unblocked(metric):
+    """Above GATHER_BLOCK pairs gather_dists scores block by block; every
+    pair's distance is bitwise the one an unblocked call gives."""
+    key = jax.random.PRNGKey(3)
+    x = jax.random.normal(key, (3000, 16))
+    p = D.GATHER_BLOCK + 4097            # one full block + a padded tail
+    u = jax.random.randint(jax.random.PRNGKey(4), (p,), -1, 3000)
+    v = jax.random.randint(jax.random.PRNGKey(5), (p,), -1, 3000)
+    got = np.asarray(D.gather_dists(x, u, v, metric))
+    step = D.GATHER_BLOCK // 2
+    want = np.concatenate([
+        np.asarray(D.gather_dists(x, u[i:i + step], v[i:i + step], metric))
+        for i in range(0, p, step)])
+    assert got.shape == (p,)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    assert np.all(np.isinf(got[(np.asarray(u) < 0) | (np.asarray(v) < 0)]))

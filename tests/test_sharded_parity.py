@@ -27,6 +27,7 @@ from repro.core import search as S
 from repro.core import shard
 from repro.data.synthetic import VectorDatasetSpec, clustered_vectors
 from repro.distributed import sharding as SH
+from repro.launch.mesh import make_mesh
 
 N = 700                    # 700 % 8 == 4: row padding always active at 8 dev
 METRICS = ("l2", "ip", "cos")
@@ -49,7 +50,7 @@ def _nsg_cfg(metric):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((jax.device_count(),), ("data",))
+    return make_mesh((jax.device_count(),), ("data",))
 
 
 @pytest.fixture(scope="module")
@@ -114,6 +115,17 @@ def test_sharded_build_requires_bucketed_merge(corpus, mesh):
     cfg = rd.RNNDescentConfig(s=8, r=16, t1=2, t2=2, capacity=24, merge="sort")
     with pytest.raises(ValueError, match="bucketed"):
         rd.build(x, cfg, KEY, mesh=mesh)
+
+
+def test_explicit_axis_mesh_rejected(corpus, rnn_graph):
+    """jax.make_mesh defaults to Explicit axes under JAX 0.9; the sharded
+    build and search say so instead of failing deep inside a reshape."""
+    x, q = corpus
+    explicit = jax.make_mesh((jax.device_count(),), ("data",))
+    with pytest.raises(ValueError, match="launch.mesh.make_mesh"):
+        rd.build(x, _rnn_cfg("l2"), KEY, mesh=explicit)
+    with pytest.raises(ValueError, match="launch.mesh.make_mesh"):
+        S.search_tiled(x, rnn_graph, q, 0, S.SearchConfig(), mesh=explicit)
 
 
 def test_mesh_resolves_ann_axes(mesh):

@@ -19,13 +19,14 @@ CI mesh job).
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hyp import HAVE_HYPOTHESIS, given, settings, st  # degrades to skip
+from _hyp import HAVE_HYPOTHESIS, example, given, settings, st  # degrades to skip
 
 from repro.core import graph as G
 from repro.core import shard
+from repro.launch.mesh import make_mesh
 from test_bucketed_merge import _canon, _check_row_invariant, _rand_graph
 
-MESH = jax.make_mesh((jax.device_count(),), ("data",))
+MESH = make_mesh((jax.device_count(),), ("data",))
 
 if HAVE_HYPOTHESIS:
     _params = dict(
@@ -49,6 +50,7 @@ def _graph(seed, n, m, metric):
 
 @given(**_params)
 @settings(max_examples=25, deadline=None)
+@example(seed=0, n=17, m=4, r=2, metric="l2")  # found failing under JAX 0.9
 def test_reverse_exchange_matches_sort_oracle(seed, n, m, r, metric):
     """Injective bucket width: sharded reverse edges == lexsort oracle under
     both degree caps (content equality — tie order may differ), and bitwise
@@ -73,6 +75,7 @@ def test_reverse_exchange_matches_sort_oracle(seed, n, m, r, metric):
 
 @given(**_params)
 @settings(max_examples=15, deadline=None)
+@example(seed=0, n=17, m=4, r=2, metric="l2")  # found failing under JAX 0.9
 def test_reverse_exchange_tiny_buckets_match_single_device(seed, n, m, r,
                                                            metric):
     """Lossy bucket widths (collisions drop edges): the sharded exchange must

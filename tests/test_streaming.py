@@ -29,6 +29,7 @@ from repro.core import graph as G
 from repro.core import rnn_descent as rd
 from repro.core import search as S
 from repro.data.synthetic import VectorDatasetSpec, clustered_vectors
+from repro.launch.mesh import make_mesh
 from repro.streaming import StreamingANN, StreamingConfig
 from repro.streaming import store as ST
 from repro.streaming import updates as U
@@ -268,7 +269,7 @@ def test_sharded_streaming_updates_bitwise_equal(corpus):
     min-fold; delete repair is per-row). 1-wide under plain tier-1 (still
     the full shard_map path), 8-wide in the CI mesh job."""
     x, _ = corpus
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     g = rd.build(x[:420], CFG.build, jax.random.PRNGKey(1))
     st = ST.from_built(x[:420], g, capacity=700)
 

@@ -1,0 +1,93 @@
+"""Ahead-of-time compiles of the Pallas kernels for a TPU v5e chip.
+
+The interpret-mode tests (test_kernels.py, test_beam_score.py, test_quant.py)
+pin results on the CPU but never run Mosaic, which refuses constructs the
+interpreter accepts (value-level dynamic slicing, 8-bit compares, dots with no
+free dim). Here each kernel variant that ships for the chip is lowered and
+compiled against a *described* v5e topology — the TPU compiler runs on the
+host, no chip is attached — at the widths the index runs: M=128 candidates
+per row, d=128 (SIFT) and d=960 (GIST) for the prune, a VMEM-resident corpus
+for the beam step. Each compile must emit the kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import: only one
+process at a time may load the TPU library, and every pytest worker imports
+this file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.beam_score import ops as beam_ops
+from repro.kernels.rng_prune import ops as prune_ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler can be loaded here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("d", [128, 960])
+@pytest.mark.parametrize("variant", ["f32", "bf16", "int8"])
+def test_rng_prune_compiles(one_chip, variant, d):
+    n_pts, rows, m = 4096, 64, 128
+    graph = [((rows, m), jnp.int32), ((rows, m), jnp.float32),
+             ((rows, m), jnp.uint8)]
+    if variant == "int8":
+        fn = functools.partial(prune_ops.rng_prune_int8, interpret=False)
+        hlo = _compile(fn, one_chip, ((n_pts, d), jnp.int8),
+                       ((d,), jnp.float32), ((d,), jnp.float32), *graph)
+    else:
+        fn = functools.partial(prune_ops.rng_prune, interpret=False,
+                               gram_dtype=variant)
+        hlo = _compile(fn, one_chip, ((n_pts, d), jnp.float32), *graph)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip", "cos"])
+@pytest.mark.parametrize("variant", ["f32", "bf16", "int8"])
+def test_beam_score_compiles(one_chip, variant, metric):
+    n, cap, d, b, k = 2048, 128, 128, 256, 32
+    common = [((n, cap), jnp.int32), ((b,), jnp.int32), ((b, d), jnp.float32)]
+    if variant == "int8":
+        fn = functools.partial(beam_ops.beam_score_int8, k=k, metric=metric,
+                               interpret=False)
+        hlo = _compile(fn, one_chip, ((n, d), jnp.int8), ((d,), jnp.float32),
+                       ((d,), jnp.float32), *common)
+    else:
+        fn = functools.partial(beam_ops.beam_score, k=k, metric=metric,
+                               interpret=False, gram_dtype=variant)
+        hlo = _compile(fn, one_chip, ((n, d), jnp.float32), *common)
+    assert "tpu_custom_call" in hlo
+
+
+def test_beam_score_pq_refuses_compiled_backend():
+    """The PQ beam kernel's LUT read is a 3-D gather Mosaic cannot lower: a
+    compiled (non-interpret) call must say so, never fall back."""
+    n, cap, mq, b = 512, 16, 8, 8
+    with pytest.raises(ValueError, match="does not compile for TPU"):
+        beam_ops.beam_score_pq(
+            jnp.zeros((n, mq), jnp.uint8), jnp.zeros((n, cap), jnp.int32),
+            jnp.zeros((b,), jnp.int32), jnp.zeros((b, mq, 256)),
+            jnp.zeros((mq, 256)), jnp.zeros((b,)), k=8, interpret=False)

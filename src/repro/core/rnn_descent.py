@@ -128,32 +128,34 @@ def prune_rows(
 
     ``qx``: int8 codes for the code-gathering prune (see
     :func:`_fused_prune_chunk`); ``None`` keeps the f32/bf16 path."""
-    n_rows, m = ids.shape
-    chunk = min(cfg.chunk, n_rows)
-    pad = (-n_rows) % chunk
-    ids = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
-    dists = jnp.pad(dists, ((0, pad), (0, 0)), constant_values=jnp.inf)
-    flags = jnp.pad(flags, ((0, pad), (0, 0)), constant_values=G.OLD)
+    with jax.named_scope("rnnd.prune"):    # the phase's device-trace name
+        n_rows, m = ids.shape
+        chunk = min(cfg.chunk, n_rows)
+        pad = (-n_rows) % chunk
+        ids = jnp.pad(ids, ((0, pad), (0, 0)), constant_values=-1)
+        dists = jnp.pad(dists, ((0, pad), (0, 0)), constant_values=jnp.inf)
+        flags = jnp.pad(flags, ((0, pad), (0, 0)), constant_values=G.OLD)
 
-    def one_chunk(args):
-        cid, cdist, cflag = args
-        return _fused_prune_chunk(x, cid, cdist, cflag, cfg.metric,
-                                  cfg.use_pallas, cfg.effective_gram_dtype,
-                                  qx=qx)
+        def one_chunk(args):
+            cid, cdist, cflag = args
+            return _fused_prune_chunk(x, cid, cdist, cflag, cfg.metric,
+                                      cfg.use_pallas, cfg.effective_gram_dtype,
+                                      qx=qx)
 
-    keep, red_w, red_d = jax.lax.map(
-        one_chunk,
-        (ids.reshape(-1, chunk, m), dists.reshape(-1, chunk, m), flags.reshape(-1, chunk, m)),
-    )
-    # The barrier stops XLA from fusing a caller's flatten of these outputs
-    # (the sweep's candidate lists) into the map's stacked (rows/chunk,
-    # chunk, m) layout: that fusion leaves a relayout of a packed bool mask
-    # to an (n*m, 1) column whose TPU code generation time grows with n.
-    return jax.lax.optimization_barrier((
-        keep.reshape(-1, m)[:n_rows],
-        red_w.reshape(-1, m)[:n_rows],
-        red_d.reshape(-1, m)[:n_rows],
-    ))
+        keep, red_w, red_d = jax.lax.map(
+            one_chunk,
+            (ids.reshape(-1, chunk, m), dists.reshape(-1, chunk, m),
+             flags.reshape(-1, chunk, m)),
+        )
+        # The barrier stops XLA from fusing a caller's flatten of these outputs
+        # (the sweep's candidate lists) into the map's stacked (rows/chunk,
+        # chunk, m) layout: that fusion leaves a relayout of a packed bool mask
+        # to an (n*m, 1) column whose TPU code generation time grows with n.
+        return jax.lax.optimization_barrier((
+            keep.reshape(-1, m)[:n_rows],
+            red_w.reshape(-1, m)[:n_rows],
+            red_d.reshape(-1, m)[:n_rows],
+        ))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -167,26 +169,31 @@ def update_neighbors(x: jnp.ndarray, g: G.Graph, cfg: RNNDescentConfig,
       * a dropped v yields the replacement edge (w -> v) with d(v, w) — the
         simultaneous "NN-Descent join" that keeps v reachable from u via w;
       * kept entries become "old"; replacement edges are inserted "new".
+
+    The prune and the merge run under the ``jax.named_scope`` names
+    ``rnnd.prune`` and ``rnnd.merge``: metadata that a device trace keeps
+    on every compiled op, no op of their own.
     """
     keep, red_w, red_d = prune_rows(x, g.neighbors, g.dists, g.flags, cfg,
                                     qx=qx)
 
-    # Surviving adjacency: kept entries, flags forced to "old" (Alg. 4 L16).
-    pruned = G.Graph(
-        neighbors=jnp.where(keep, g.neighbors, -1),
-        dists=jnp.where(keep, g.dists, jnp.inf),
-        flags=jnp.zeros_like(g.flags),
-    )
-    pruned = G.sort_rows(pruned)
+    with jax.named_scope("rnnd.merge"):    # the phase's device-trace name
+        # Surviving adjacency: kept entries, flags forced to "old" (Alg. 4 L16).
+        pruned = G.Graph(
+            neighbors=jnp.where(keep, g.neighbors, -1),
+            dists=jnp.where(keep, g.dists, jnp.inf),
+            flags=jnp.zeros_like(g.flags),
+        )
+        pruned = G.sort_rows(pruned)
 
-    # Replacement edges (w -> v): scatter-merge into w's rows, flagged "new".
-    cand_src = red_w.reshape(-1)                                       # w
-    cand_dst = jnp.where(red_w >= 0, g.neighbors, -1).reshape(-1)      # v
-    cand_dist = red_d.reshape(-1)
-    return G.merge_candidate_edges(
-        pruned, cand_src, cand_dst, cand_dist,
-        merge=cfg.merge, n_buckets=cfg.n_buckets,
-    )
+        # Replacement edges (w -> v): scatter-merge into w's rows, flagged "new".
+        cand_src = red_w.reshape(-1)                                       # w
+        cand_dst = jnp.where(red_w >= 0, g.neighbors, -1).reshape(-1)      # v
+        cand_dist = red_d.reshape(-1)
+        return G.merge_candidate_edges(
+            pruned, cand_src, cand_dst, cand_dist,
+            merge=cfg.merge, n_buckets=cfg.n_buckets,
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

@@ -75,6 +75,12 @@ interchangeable implementations selected by ``SearchConfig.use_pallas``
 ``SearchConfig.gram_dtype="bf16"`` gathers neighbor vectors in bfloat16
 (the rng_prune convention — halves gather traffic, f32 accumulation);
 ``SearchConfig.kernel_tile_b`` sizes the kernel's lane tile.
+
+Each iteration's phases run under ``jax.named_scope`` names —
+``beam.select`` (frontier pick, retirement), ``beam.score`` (gather +
+score), ``beam.visited`` (dedup + visited table), ``beam.topk`` (beam
+merge) — which the compiled ops carry as metadata into a device trace; a
+scope adds no op, so results are unchanged.
 """
 from __future__ import annotations
 
@@ -380,19 +386,20 @@ def _search_impl(
 
     def body(state):
         beam_ids, beam_d, expanded, visited, done, it, work, _ = state
-        frontier = jnp.where(expanded, jnp.inf, beam_d)
-        slot = jnp.argmin(frontier, axis=1)                       # (B,)
-        best_unexp = frontier[rows, slot]
-        # per-lane retirement: nothing unexpanded can displace a beam entry.
-        # In-beam candidates always satisfy best_unexp <= beam_d[:, -1] (merge
-        # already evicted anything worse), so the operative trigger is an
-        # exhausted frontier; retired lanes stop mutating state and let their
-        # tile's while_loop exit without waiting on other tiles.
-        done = done | (best_unexp > beam_d[:, -1]) | ~jnp.isfinite(best_unexp)
-        active = ~done
-        work = work + active.astype(jnp.int32)
-        u = jnp.where(active, beam_ids[rows, slot], 0)
-        expanded = expanded.at[rows, slot].max(active)
+        with jax.named_scope("beam.select"):
+            frontier = jnp.where(expanded, jnp.inf, beam_d)
+            slot = jnp.argmin(frontier, axis=1)                       # (B,)
+            best_unexp = frontier[rows, slot]
+            # per-lane retirement: nothing unexpanded can displace a beam entry.
+            # In-beam candidates always satisfy best_unexp <= beam_d[:, -1] (merge
+            # already evicted anything worse), so the operative trigger is an
+            # exhausted frontier; retired lanes stop mutating state and let their
+            # tile's while_loop exit without waiting on other tiles.
+            done = done | (best_unexp > beam_d[:, -1]) | ~jnp.isfinite(best_unexp)
+            active = ~done
+            work = work + active.astype(jnp.int32)
+            u = jnp.where(active, beam_ids[rows, slot], 0)
+            expanded = expanded.at[rows, slot].max(active)
 
         # fused gather+score (Eq. 4 prefix slice + distance evaluation): the
         # kernel and the jnp oracle share one scoring function, so the two
@@ -400,61 +407,64 @@ def _search_impl(
         # candidate block lives (VMEM vs an HBM intermediate). Under int8/pq
         # the gather reads *codes* (4x / d/m-fold less traffic) and decode
         # happens in-register next to the distance math.
-        if hooks is not None:
-            # owner-contribute collectives (corpus-sharded); bitwise equal
-            # to the jnp oracle below — including the coded paths
-            nbrs, cand_d = hooks.beam(u)
-        elif qmode == "int8":
-            if cfg.use_pallas:
-                nbrs, cand_d, _ = beam_score_int8(
-                    qx.codes, qx.scale, qx.zero, g.neighbors, u, queries,
-                    k=k, metric=cfg.metric, tile_b=cfg.kernel_tile_b)
+        with jax.named_scope("beam.score"):
+            if hooks is not None:
+                # owner-contribute collectives (corpus-sharded); bitwise equal
+                # to the jnp oracle below — including the coded paths
+                nbrs, cand_d = hooks.beam(u)
+            elif qmode == "int8":
+                if cfg.use_pallas:
+                    nbrs, cand_d, _ = beam_score_int8(
+                        qx.codes, qx.scale, qx.zero, g.neighbors, u, queries,
+                        k=k, metric=cfg.metric, tile_b=cfg.kernel_tile_b)
+                else:
+                    nbrs, cand_d, _ = beam_score_int8_ref(
+                        qx.codes, qx.scale, qx.zero, g.neighbors, u, queries,
+                        k=k, metric=cfg.metric)
+            elif qmode == "pq":
+                if cfg.use_pallas:
+                    nbrs, cand_d, _ = beam_score_pq(
+                        qx.codes, g.neighbors, u, lut_a, lut_b, qsq,
+                        k=k, metric=cfg.metric, tile_b=cfg.kernel_tile_b)
+                else:
+                    nbrs, cand_d, _ = beam_score_pq_ref(
+                        qx.codes, g.neighbors, u, lut_a, lut_b, qsq,
+                        k=k, metric=cfg.metric)
+            elif cfg.use_pallas:
+                nbrs, cand_d, _ = beam_score(
+                    x, g.neighbors, u, queries, k=k, metric=cfg.metric,
+                    tile_b=cfg.kernel_tile_b, gram_dtype=cfg.effective_gram_dtype)
             else:
-                nbrs, cand_d, _ = beam_score_int8_ref(
-                    qx.codes, qx.scale, qx.zero, g.neighbors, u, queries,
-                    k=k, metric=cfg.metric)
-        elif qmode == "pq":
-            if cfg.use_pallas:
-                nbrs, cand_d, _ = beam_score_pq(
-                    qx.codes, g.neighbors, u, lut_a, lut_b, qsq,
-                    k=k, metric=cfg.metric, tile_b=cfg.kernel_tile_b)
-            else:
-                nbrs, cand_d, _ = beam_score_pq_ref(
-                    qx.codes, g.neighbors, u, lut_a, lut_b, qsq,
-                    k=k, metric=cfg.metric)
-        elif cfg.use_pallas:
-            nbrs, cand_d, _ = beam_score(
-                x, g.neighbors, u, queries, k=k, metric=cfg.metric,
-                tile_b=cfg.kernel_tile_b, gram_dtype=cfg.effective_gram_dtype)
-        else:
-            nbrs, cand_d, _ = beam_score_ref(
-                x, g.neighbors, u, queries, k=k, metric=cfg.metric,
-                gram_dtype=cfg.effective_gram_dtype)
+                nbrs, cand_d, _ = beam_score_ref(
+                    x, g.neighbors, u, queries, k=k, metric=cfg.metric,
+                    gram_dtype=cfg.effective_gram_dtype)
         # cand_ok: per-candidate validity (real neighbor slot, live lane) —
         # distinct from the function-level `valid` tombstone mask
-        cand_ok = (nbrs >= 0) & active[:, None]
-        if dense:
-            seen = visited[rows[:, None], jnp.maximum(nbrs, 0)]
-            fresh = cand_ok & ~seen
-            ins_idx = jnp.where(fresh, nbrs, n)                   # n = scratch slot
-            visited = visited.at[rows[:, None], ins_idx].set(True)
-        else:
-            # exact candidate-vs-beam dedup backs up the lossy hash table:
-            # a lost insertion can cost a re-score, never a duplicate result
-            in_beam = jnp.any(nbrs[:, :, None] == beam_ids[:, None, :], axis=-1)
-            seen, visited = _visited_lookup_insert(
-                visited, nbrs, cand_ok & ~in_beam, rows, cfg.probes)
-            fresh = cand_ok & ~seen & ~in_beam
+        with jax.named_scope("beam.visited"):
+            cand_ok = (nbrs >= 0) & active[:, None]
+            if dense:
+                seen = visited[rows[:, None], jnp.maximum(nbrs, 0)]
+                fresh = cand_ok & ~seen
+                ins_idx = jnp.where(fresh, nbrs, n)                   # n = scratch slot
+                visited = visited.at[rows[:, None], ins_idx].set(True)
+            else:
+                # exact candidate-vs-beam dedup backs up the lossy hash table:
+                # a lost insertion can cost a re-score, never a duplicate result
+                in_beam = jnp.any(nbrs[:, :, None] == beam_ids[:, None, :], axis=-1)
+                seen, visited = _visited_lookup_insert(
+                    visited, nbrs, cand_ok & ~in_beam, rows, cfg.probes)
+                fresh = cand_ok & ~seen & ~in_beam
 
-        nd = jnp.where(fresh, cand_d, jnp.inf)
+        with jax.named_scope("beam.topk"):
+            nd = jnp.where(fresh, cand_d, jnp.inf)
 
-        all_d = jnp.concatenate([beam_d, nd], axis=1)
-        all_ids = jnp.concatenate([beam_ids, jnp.where(fresh, nbrs, -1)], axis=1)
-        all_exp = jnp.concatenate([expanded, ~fresh], axis=1)
-        neg_d, order = jax.lax.top_k(-all_d, cfg.l)               # L smallest
-        beam_d = -neg_d
-        beam_ids = jnp.take_along_axis(all_ids, order, axis=1)
-        expanded = jnp.take_along_axis(all_exp, order, axis=1)
+            all_d = jnp.concatenate([beam_d, nd], axis=1)
+            all_ids = jnp.concatenate([beam_ids, jnp.where(fresh, nbrs, -1)], axis=1)
+            all_exp = jnp.concatenate([expanded, ~fresh], axis=1)
+            neg_d, order = jax.lax.top_k(-all_d, cfg.l)               # L smallest
+            beam_d = -neg_d
+            beam_ids = jnp.take_along_axis(all_ids, order, axis=1)
+            expanded = jnp.take_along_axis(all_exp, order, axis=1)
         return (beam_ids, beam_d, expanded, visited, done, it + 1, work,
                 any_fn(~done))
 
@@ -593,8 +603,10 @@ def search_tiled(
 
     Observability: this host wrapper dispatches to one jitted program
     (``_search_tiled_jit`` — the only compiled entry point, unchanged by
-    tracing). With ``repro.obs`` enabled and concrete operands it wraps the
-    dispatch in a ``search/tiled`` span, blocks for an execution-accurate
+    tracing). With concrete operands the dispatch runs under the spans
+    ``search/tiled`` and ``search/dispatch``, which land on a running
+    profiler's trace even with ``repro.obs`` off. With ``repro.obs`` enabled
+    the ``search/tiled`` span also blocks for an execution-accurate
     duration, and folds the ``with_stats`` lane-work counters into the
     metrics registry; called with tracers (inside an outer jit or
     ``make_jaxpr``) it degrades to the plain dispatch, so traced callers
@@ -602,15 +614,16 @@ def search_tiled(
     program with or without tracing.
     """
     from repro.obs import trace as _tr
-    if not _tr.enabled() or isinstance(queries, jax.core.Tracer):
-        return _search_tiled_jit(x, g, queries, entry_points, cfg, tile_b,
-                                 mesh, valid, qx, shard, with_stats,
-                                 lane_valid)
-    from repro.obs import metrics as _mx
+    args = (x, g, queries, entry_points, cfg, tile_b, mesh, valid, qx, shard,
+            with_stats, lane_valid)
+    if isinstance(queries, jax.core.Tracer):
+        return _search_tiled_jit(*args)
     with _tr.span("search/tiled") as sp:
-        out = _search_tiled_jit(x, g, queries, entry_points, cfg, tile_b,
-                                mesh, valid, qx, shard, with_stats,
-                                lane_valid)
+        with _tr.span("search/dispatch"):
+            out = _search_tiled_jit(*args)
+        if not sp:
+            return out
+        from repro.obs import metrics as _mx
         out = jax.block_until_ready(out)
         b = int(queries.shape[0])
         sp.set(b=b, tile_b=int(tile_b), shard=shard, l=cfg.l, k=cfg.k,

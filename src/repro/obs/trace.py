@@ -11,11 +11,18 @@ One process-wide tracer, off by default. Instrumented code opens spans::
 Contracts (tests/test_obs.py pins each):
 
 * **Zero-cost when disabled** — :func:`span` performs a single flag check
-  and returns a shared no-op singleton: no event is allocated, nothing is
-  recorded, ``bool(sp)`` is False so call sites skip attribute computation
-  (and any ``block_until_ready`` they add for span accuracy). The traced
-  and untraced paths issue the *same* jitted programs, so results are
-  bitwise identical either way — tracing may only add host-side reads.
+  and one ``TraceAnnotation.is_enabled()`` call and returns a shared no-op
+  singleton: no event is allocated, nothing is recorded, ``bool(sp)`` is
+  False so call sites skip attribute computation (and any
+  ``block_until_ready`` they add for span accuracy). The traced and
+  untraced paths issue the *same* jitted programs, so results are bitwise
+  identical either way — tracing may only add host-side reads.
+* **On the profiler's clock** — while a JAX profiler collects
+  (``jax.profiler.trace``/``start_trace``), every span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so it lands on the
+  profile's host plane beside the device ops. With tracing off the span
+  only annotates: it records nothing here and is falsy, so no ``if sp:``
+  block (and no ``block_until_ready``) runs under a profiler either.
 * **Monotonic timestamps** — spans are stamped with ``time.perf_counter``
   relative to the tracer epoch (reset on :func:`reset`), the same clock
   domain the serving front end uses, so retroactive request spans
@@ -40,6 +47,8 @@ import threading
 import time
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 _lock = threading.Lock()
 _enabled = False
 _origin = 0.0                 # perf_counter at the last reset()
@@ -62,7 +71,7 @@ class Span:
     attributes with :meth:`set`. Truthy — the disabled-path sentinel
     :data:`NOOP` is falsy, so ``if sp:`` gates trace-only work."""
 
-    __slots__ = ("name", "t0", "dur_s", "tid", "depth", "attrs")
+    __slots__ = ("name", "t0", "dur_s", "tid", "depth", "attrs", "_ann")
 
     def __init__(self, name: str, attrs: dict[str, Any]):
         self.name = name
@@ -71,6 +80,7 @@ class Span:
         self.dur_s = 0.0
         self.tid = 0
         self.depth = 0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -86,11 +96,17 @@ class Span:
         self.depth = len(stack)
         self.tid = threading.get_ident()
         stack.append(self)
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
         self.t0 = _now()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.dur_s = _now() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         stack = getattr(_tls, "stack", [])
         if stack and stack[-1] is self:
             stack.pop()
@@ -123,9 +139,31 @@ class _NoopSpan:
 NOOP = _NoopSpan()
 
 
+class _AnnotationSpan(_NoopSpan):
+    """A span while tracing is off and a profiler collects: it marks the
+    profile's host plane with its name and is otherwise :data:`NOOP`
+    (falsy, records nothing)."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> "_AnnotationSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
 def span(name: str, **attrs):
-    """Open a span (context manager). Single flag check when disabled."""
+    """Open a span (context manager). When disabled: a flag check and a
+    check for a collecting profiler."""
     if not _enabled:
+        if TraceAnnotation.is_enabled():
+            return _AnnotationSpan(name)
         return NOOP
     return Span(name, attrs)
 

@@ -41,6 +41,7 @@ from repro.core import graph as G
 from repro.quant import QuantizedCorpus, encode_corpus
 from repro.core import rnn_descent as rd
 from repro.core import search as S
+from repro.obs import trace as _tr
 from repro.streaming import store as ST
 from repro.streaming import updates as U
 
@@ -137,15 +138,17 @@ class StreamingANN:
                     f"search config requests quant mode {cfg.quant.mode!r} "
                     f"but the store's codes are {st.qx.mode!r}")
             qx = st.qx
-        valid = ST.active_mask(st)
-        if entry_points is None:
-            entry_points = S.default_entry_point(st.x, cfg.metric,
-                                                 valid=valid)
-        return S.search_tiled(st.x, st.graph, jnp.asarray(queries),
-                              entry_points, cfg, tile_b=tile_b,
-                              mesh=self.mesh, valid=valid, qx=qx,
-                              shard=shard, with_stats=with_stats,
-                              lane_valid=lane_valid)
+        with _tr.span("streaming/search"):
+            with _tr.span("streaming/entry"):
+                valid = ST.active_mask(st)
+                if entry_points is None:
+                    entry_points = S.default_entry_point(st.x, cfg.metric,
+                                                         valid=valid)
+            return S.search_tiled(st.x, st.graph, jnp.asarray(queries),
+                                  entry_points, cfg, tile_b=tile_b,
+                                  mesh=self.mesh, valid=valid, qx=qx,
+                                  shard=shard, with_stats=with_stats,
+                                  lane_valid=lane_valid)
 
     # -------------------------------------------------------------- updates
     def insert(self, new_x) -> np.ndarray:

@@ -250,6 +250,98 @@ class TestDisabledNoOp:
         assert "search/tiled" in names
 
 
+# ------------------------------------------------- on the profiler's clock
+def _host_event_names(trace_dir) -> set:
+    import glob
+    import os
+
+    path, = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    prof = jax.profiler.ProfileData.from_file(path)
+    return {e.name for plane in prof.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+class TestProfilerClock:
+    """Spans mirror into ``jax.profiler.TraceAnnotation`` while a profiler
+    collects; the program's phases carry ``jax.named_scope`` names into the
+    compiled programs' op metadata."""
+
+    def test_off_without_profiler_is_noop(self):
+        assert not jax.profiler.TraceAnnotation.is_enabled()
+        assert T.span("streaming/search") is T.NOOP
+
+    def test_obs_off_under_profiler_only_annotates(self, tmp_path):
+        with jax.profiler.trace(str(tmp_path)):
+            sp = T.span("unit/annotated")
+            with sp:
+                assert not sp
+                sp.set(ignored=1)
+                jax.block_until_ready(jax.numpy.ones(4) + 1)
+        assert sp is not T.NOOP
+        assert T.events() == []
+        assert len(metrics.REGISTRY) == 0
+        assert "unit/annotated" in _host_event_names(tmp_path)
+
+    def test_obs_on_under_profiler_records_and_annotates(self, tmp_path):
+        with T.enabled_scope(), jax.profiler.trace(str(tmp_path)):
+            with T.span("unit/both") as sp:
+                assert sp
+        assert [e["name"] for e in T.events()] == ["unit/both"]
+        assert "unit/both" in _host_event_names(tmp_path)
+
+    @pytest.mark.parametrize("program,scope", [
+        ("update_neighbors", "rnnd.prune"),
+        ("update_neighbors", "rnnd.merge"),
+        ("search_tiled", "beam.select"),
+        ("search_tiled", "beam.score"),
+        ("search_tiled", "beam.visited"),
+        ("search_tiled", "beam.topk"),
+    ])
+    def test_compiled_program_names_its_phase(self, tiny, program, scope):
+        import re
+
+        x, q = tiny
+        g = rd.random_init(jax.random.PRNGKey(0), x, CFG)
+        if program == "update_neighbors":
+            lowered = rd.update_neighbors.lower(x, g, CFG)
+        else:
+            lowered = S._search_tiled_jit.lower(x, g, q, 0, SCFG, 16)
+        # a persistent compile cache keys programs without their metadata:
+        # key by it here, so an entry compiled without the scopes is no hit
+        key = "jax_compilation_cache_include_metadata_in_key"
+        before = getattr(jax.config, key)
+        jax.config.update(key, True)
+        try:
+            text = lowered.compile().as_text()
+        finally:
+            jax.config.update(key, before)
+        assert re.search(r'op_name="([^"]*/)?' + re.escape(scope) + '[/"]',
+                         text)
+
+    def test_streaming_search_bitwise_under_profiler(self, tiny, tmp_path):
+        from repro.streaming import StreamingANN, StreamingConfig
+
+        x, q = tiny
+        ann = StreamingANN.from_corpus(x, StreamingConfig(build=CFG),
+                                       key=jax.random.PRNGKey(1))
+        ref = [np.asarray(a) for a in ann.search(q, SCFG, tile_b=16)]
+        with jax.profiler.trace(str(tmp_path)):
+            got = [np.asarray(a) for a in ann.search(q, SCFG, tile_b=16)]
+        assert all(a.tobytes() == b.tobytes() for a, b in zip(got, ref))
+        names = _host_event_names(tmp_path)
+        assert {"streaming/search", "streaming/entry",
+                "search/dispatch"} <= names
+        assert T.events() == []
+
+    def test_obs_smoke_session_passes(self, tmp_path):
+        """``python -m repro.obs``: the scripted build + serve session and
+        its contract checks."""
+        from repro.obs.__main__ import main
+
+        assert main(["--out", str(tmp_path)]) == 0
+
+
 # ------------------------------------------------------------ jax bridge
 class TestJaxHooks:
     def test_compile_events_captured(self):

@@ -21,21 +21,36 @@ Two interchangeable visited implementations, selected by
 
 ``"hashed"`` — the production default: a per-query open-addressed hash table
     of ``slots`` int32 entries (``slots`` a power of two sized from L,
-    max_iters and K — see :func:`resolve_slots`), probed linearly ``probes``
-    times per lookup/insert. Memory is ``B * slots * 4`` bytes, **independent
-    of n**: the default config (L=64, K=32, max_iters=256) resolves to 32768
-    slots = 128 KiB per lane, so a 256-lane tile carries 32 MiB of visited
-    state no matter whether the corpus holds 10^4 or 10^9 vectors.
+    max_iters and K — see :func:`resolve_slots`). Memory is
+    ``B * slots * 4`` bytes, **independent of n**: the default config (L=64,
+    K=32, max_iters=256) resolves to 32768 slots = 128 KiB per lane, so a
+    256-lane tile carries 32 MiB of visited state no matter whether the
+    corpus holds 10^4 or 10^9 vectors.
+
+    Layout: a lane's slots are ``slots / W`` bucket rows of ``W = min(128,
+    slots)`` entries, and a tile's table is one 2-D ``(B * slots / W, W)``
+    array, so one bucket row lines up with the TPU's 128-lane minor
+    dimension (bytes unchanged). A multiplicative hash of the id picks the
+    bucket, ``h mod (slots / W)``, and a first slot inside it; the
+    ``probes`` linear probes wrap inside that bucket's row. A lookup gathers
+    one row per candidate and tests the probe window with element-wise masks
+    over the whole row; an insert writes the candidate's whole row back with
+    the id at its first empty window slot. Each step is one row gather and
+    one row scatter on the table, which stays in place: it is never
+    reshaped to (B, slots), and no single element is gathered.
 
 The hash table stores only genuinely visited vertex ids, so membership tests
-have **no false positives** — a candidate is never wrongly skipped. Lost
-insertions (probe overflow, or two fresh candidates racing for one slot in a
-single scatter) can only yield false *negatives*: a previously evicted vertex
-may be re-scored. Because the beam's worst distance is monotonically
-non-increasing, a re-scored evicted vertex can never re-enter the beam with a
-strictly better rank, and an explicit candidate-vs-beam dedup keeps the beam
-duplicate-free — so hashed search converges to the *same* result as the dense
-oracle, spending at most a few extra iterations. Trust ``"hashed"`` for
+have **no false positives** — a candidate is never wrongly skipped. An insert
+is lost in two ways only: every slot of the id's probe window is full, or two
+fresh candidates of one lane land in one bucket in the same step, and the
+later row write wins. Both rows are built from the same old row, so nothing
+inserted earlier is ever erased. ``search_tiled(with_stats=True)`` counts the
+lost inserts as ``visited_lost``. A lost insert can only yield a false
+*negative*: the vertex may be re-scored. Because the beam's worst distance is
+monotonically non-increasing, a re-scored vertex can never re-enter the beam
+with a strictly better rank, and an explicit candidate-vs-beam dedup keeps
+the beam duplicate-free — so hashed search converges to the *same* result as
+the dense oracle, with the same beam expansions. Trust ``"hashed"`` for
 serving; use ``"dense"`` as the exact reference in tests and when measuring
 the approximation (equal results at equal L is asserted in
 ``tests/test_search.py``).
@@ -209,35 +224,63 @@ def visited_state_bytes(cfg: SearchConfig, n: int, lanes: int, n_entry: int = 1)
 
 
 # --------------------------------------------------------------- visited table
-def _probe_slots(ids: jnp.ndarray, slots: int, probes: int) -> jnp.ndarray:
-    """(..., C) ids -> (..., C, probes) table indices (Knuth multiplicative
-    hash + bit mix, linear probing; ``slots`` is a power of two)."""
+def _bucket_width(slots: int) -> int:
+    """Slots per bucket row of the hashed table: one row spans the chip's
+    128-lane minor dimension (the whole table when it is smaller)."""
+    return min(128, slots)
+
+
+def _probe_buckets(ids: jnp.ndarray, slots: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(..., C) ids -> ((..., C) bucket, (..., C) offset): a Knuth
+    multiplicative hash + bit mix picks the bucket, ``h mod (slots / W)``,
+    and the next bits the first probed slot inside it (``slots`` a power of
+    two)."""
+    w = _bucket_width(slots)
+    nb = slots // w
     h = ids.astype(jnp.uint32) * jnp.uint32(2654435761)
     h = h ^ (h >> jnp.uint32(16))
-    probe = h[..., None] + jnp.arange(probes, dtype=jnp.uint32)
-    return (probe & jnp.uint32(slots - 1)).astype(jnp.int32)
+    bucket = h & jnp.uint32(nb - 1)
+    offset = (h >> jnp.uint32(nb.bit_length() - 1)) & jnp.uint32(w - 1)
+    return bucket.astype(jnp.int32), offset.astype(jnp.int32)
 
 
 def _visited_lookup_insert(
     table: jnp.ndarray, ids: jnp.ndarray, want: jnp.ndarray,
     rows: jnp.ndarray, probes: int,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Membership test + insert for a (B, C) id batch against (B, slots).
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Membership test + insert for a (B, C) id batch against the
+    (B * slots / W, W) bucket-row table.
 
-    Returns (seen, new_table). Only ``want`` lanes insert. No false
-    positives ever; insertions may be lost to probe overflow or same-slot
-    scatter races (safe: the vertex is just eligible for re-scoring)."""
-    slots = table.shape[1]
-    pidx = _probe_slots(ids, slots, probes)                       # (B, C, P)
-    vals = table[rows[:, None, None], pidx]                       # (B, C, P)
-    seen = jnp.any(vals == ids[..., None], axis=-1)               # (B, C)
-    empty = vals == -1
-    first_empty = jnp.argmax(empty, axis=-1)                      # (B, C)
-    ins_slot = jnp.take_along_axis(pidx, first_empty[..., None], axis=-1)[..., 0]
-    do_ins = want & ~seen & jnp.any(empty, axis=-1)
-    tgt = jnp.where(do_ins, ins_slot, slots)                      # OOB -> dropped
-    table = table.at[rows[:, None], tgt].set(ids, mode="drop")
-    return seen, table
+    Returns (seen, new_table, lost): ``lost`` (B,) counts the ``want``
+    candidates not yet seen whose insert was dropped — a full probe window,
+    or a later candidate of the lane writing the same bucket row in this
+    step. No false positives ever; a lost insert only makes the vertex
+    eligible for re-scoring."""
+    b = rows.shape[0]
+    nb, w = table.shape[0] // b, table.shape[1]
+    bucket, offset = _probe_buckets(ids, nb * w)
+    row = rows[:, None] * nb + bucket                             # (B, C)
+    vals = table[row]                                             # (B, C, W)
+    # probe window: the `probes` slots from `offset` on, wrapping in the row
+    rank = (jnp.arange(w, dtype=jnp.int32) - offset[..., None]) & (w - 1)
+    window = rank < probes
+    seen = jnp.any(window & (vals == ids[..., None]), axis=-1)    # (B, C)
+    first = jnp.min(jnp.where(window & (vals == -1), rank, w), axis=-1)
+    wanted = want & ~seen
+    do_ins = wanted & (first < w)
+    # each insert writes its whole bucket row back (old row + its id), so
+    # two inserts into one bucket in one step keep every earlier entry and
+    # one of the two ids: the later candidate's row is counted as the one kept
+    c = ids.shape[1]
+    later = jnp.arange(c)[None, :] > jnp.arange(c)[:, None]       # (C, C)
+    overwritten = jnp.any(
+        later & do_ins[:, None, :] & (row[:, :, None] == row[:, None, :]),
+        axis=-1)
+    lost = jnp.sum(wanted & (~do_ins | overwritten), axis=-1, dtype=jnp.int32)
+    new_rows = jnp.where(rank == first[..., None], ids[..., None], vals)
+    tgt = jnp.where(do_ins, row, table.shape[0])                  # OOB -> dropped
+    table = table.at[tgt].set(new_rows, mode="drop")
+    return seen, table, lost
 
 
 # ------------------------------------------------------------ entry validation
@@ -306,11 +349,13 @@ def _search_impl(
     qx: QuantizedCorpus | None = None,  # codes when cfg.quant is int8/pq
     lane_valid: jnp.ndarray | None = None,  # (B,) bool — padded lanes False
     hooks: ScoreHooks | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Returns (ids, dists, work, iters): results plus per-lane expansion
-    counts and the executed iteration count (for the work-regression
-    accounting in :func:`search_tiled` — ``work`` sums the lanes that were
-    active each iteration, so it is invariant to how lanes are tiled)."""
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Returns (ids, dists, work, lost, iters): results plus per-lane
+    expansion counts, per-lane lost visited-table inserts and the executed
+    iteration count (for the work-regression accounting in
+    :func:`search_tiled` — ``work`` sums the lanes that were active each
+    iteration, so it is invariant to how lanes are tiled; ``lost`` is 0 under
+    ``visited="dense"``)."""
     n = x.shape[0] if hooks is None else hooks.n
     b = queries.shape[0]
     e = eps.shape[1]
@@ -367,9 +412,12 @@ def _search_impl(
     if dense:
         visited = jnp.zeros((b, n + 1), bool)
         visited = visited.at[rows[:, None], jnp.where(dup, n, eps)].set(True)
+        lost = jnp.zeros((b,), jnp.int32)
     else:
-        visited = jnp.full((b, slots), -1, jnp.int32)
-        _, visited = _visited_lookup_insert(visited, eps, ~dup, rows, cfg.probes)
+        w = _bucket_width(slots)
+        visited = jnp.full((b * slots // w, w), -1, jnp.int32)
+        _, visited, lost = _visited_lookup_insert(
+            visited, eps, ~dup, rows, cfg.probes)
 
     # padded lanes (query-count padding in search_tiled) start retired: they
     # never expand, never score, and a tile made entirely of padding exits
@@ -381,11 +429,11 @@ def _search_impl(
         # the go flag is carried in state (computed in the body / before the
         # loop) rather than reduced here: under shard="corpus" the reduction
         # is a psum and collectives cannot live in a while condition
-        _, _, _, _, _, it, _, go = state
+        _, _, _, _, _, it, _, _, go = state
         return jnp.logical_and(it < cfg.max_iters, go)
 
     def body(state):
-        beam_ids, beam_d, expanded, visited, done, it, work, _ = state
+        beam_ids, beam_d, expanded, visited, done, it, work, lost, _ = state
         with jax.named_scope("beam.select"):
             frontier = jnp.where(expanded, jnp.inf, beam_d)
             slot = jnp.argmin(frontier, axis=1)                       # (B,)
@@ -451,8 +499,9 @@ def _search_impl(
                 # exact candidate-vs-beam dedup backs up the lossy hash table:
                 # a lost insertion can cost a re-score, never a duplicate result
                 in_beam = jnp.any(nbrs[:, :, None] == beam_ids[:, None, :], axis=-1)
-                seen, visited = _visited_lookup_insert(
+                seen, visited, lost_now = _visited_lookup_insert(
                     visited, nbrs, cand_ok & ~in_beam, rows, cfg.probes)
+                lost = lost + lost_now
                 fresh = cand_ok & ~seen & ~in_beam
 
         with jax.named_scope("beam.topk"):
@@ -466,11 +515,11 @@ def _search_impl(
             beam_ids = jnp.take_along_axis(all_ids, order, axis=1)
             expanded = jnp.take_along_axis(all_exp, order, axis=1)
         return (beam_ids, beam_d, expanded, visited, done, it + 1, work,
-                any_fn(~done))
+                lost, any_fn(~done))
 
     state = (beam_ids, beam_d, expanded, visited, done, jnp.int32(0), work,
-             any_fn(~done))
-    beam_ids, beam_d, _, _, _, iters, work, _ = jax.lax.while_loop(
+             lost, any_fn(~done))
+    beam_ids, beam_d, _, _, _, iters, work, lost, _ = jax.lax.while_loop(
         cond, body, state)
     # beam rows are top_k-sorted ascending and duplicate-free by construction,
     # so the topk prefix is sorted-valid for any topk <= L
@@ -494,7 +543,8 @@ def _search_impl(
         exact = jnp.where(neg_q > -jnp.inf, exact, jnp.inf)
         neg_d, o2 = jax.lax.top_k(-exact, cfg.topk)
         out_ids = jnp.take_along_axis(rids, o2, axis=1)
-        return jnp.where(neg_d > -jnp.inf, out_ids, -1), -neg_d, work, iters
+        return (jnp.where(neg_d > -jnp.inf, out_ids, -1), -neg_d, work,
+                lost, iters)
     if valid is not None:
         # tombstone-aware serving (streaming/): masked vertices traverse the
         # beam like any other (they are live bridges in the graph) but must
@@ -506,8 +556,9 @@ def _search_impl(
         masked_d = jnp.where(ok, beam_d, jnp.inf)
         neg_d, order = jax.lax.top_k(-masked_d, cfg.topk)
         out_ids = jnp.take_along_axis(beam_ids, order, axis=1)
-        return jnp.where(neg_d > -jnp.inf, out_ids, -1), -neg_d, work, iters
-    return beam_ids[:, : cfg.topk], beam_d[:, : cfg.topk], work, iters
+        return (jnp.where(neg_d > -jnp.inf, out_ids, -1), -neg_d, work,
+                lost, iters)
+    return beam_ids[:, : cfg.topk], beam_d[:, : cfg.topk], work, lost, iters
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -532,8 +583,8 @@ def search(
     is touched only by the exact rerank tail.
     """
     eps = _validate_entry_points(entry_points, queries.shape[0], cfg.l)
-    ids, dists, _, _ = _search_impl(x, g, queries, eps, cfg, valid=valid,
-                                    qx=qx)
+    ids, dists, *_ = _search_impl(x, g, queries, eps, cfg, valid=valid,
+                                  qx=qx)
     return ids, dists
 
 
@@ -585,8 +636,9 @@ def search_tiled(
     ``qx``: encoded corpus for ``cfg.quant`` int8/pq — replicated under
     ``shard="queries"``, row-sharded under ``shard="corpus"``.
     ``with_stats``: also return a stats dict {"work": total lane-iterations
-    actually expanded (tiling-invariant), "launched": iterations executed x
-    lanes launched, "tiles", "tile_lanes"} — the accounting the
+    actually expanded (tiling-invariant), "visited_lost": hashed-table
+    inserts dropped (0 under ``visited="dense"``), "launched": iterations
+    executed x lanes launched, "tiles", "tile_lanes"} — the accounting the
     work-regression tests pin down.
 
     ``lane_valid``: optional (B,) bool — lanes marked False retire at
@@ -631,14 +683,18 @@ def search_tiled(
         if with_stats:
             stats = out[2]
             work = int(stats["work"])
+            lost = int(stats["visited_lost"])
             launched = int(stats["launched"])
             tiles = int(stats["tiles"])
-            sp.set(work=work, launched=launched, tiles=tiles,
-                   tile_lanes=int(stats["tile_lanes"]))
+            sp.set(work=work, visited_lost=lost, launched=launched,
+                   tiles=tiles, tile_lanes=int(stats["tile_lanes"]))
             reg = _mx.REGISTRY
             reg.counter("search_lane_work_total",
                         help="beam iterations actually expanded "
                              "(tiling-invariant lane work)").inc(work)
+            reg.counter("search_visited_lost_total",
+                        help="hashed visited-table inserts dropped (full "
+                             "probe window or same-bucket write)").inc(lost)
             reg.counter("search_lanes_launched_total",
                         help="iterations executed x lanes launched "
                              "(includes padded/retired lanes)").inc(launched)
@@ -754,21 +810,23 @@ def _search_tiled_jit(
             return tiles_body(xx, gg, vv, qq, rest[i], rest[i + 1],
                               rest[i + 2])
 
-        ids, dists, lane_work, tile_iters = jax.shard_map(
+        lane_spec = SH.pspec(mesh, "queries", None)
+        ids, dists, lane_work, lane_lost, tile_iters = jax.shard_map(
             dispatch, mesh=mesh,
             in_specs=tuple(specs),
-            out_specs=(qspec, qspec, SH.pspec(mesh, "queries", None),
+            out_specs=(qspec, qspec, lane_spec, lane_spec,
                        SH.pspec(mesh, "queries")),
             check_vma=False,
         )(*operands)
     else:
-        ids, dists, lane_work, tile_iters = tiles_body(
+        ids, dists, lane_work, lane_lost, tile_iters = tiles_body(
             x, g, valid, qx, q_tiles, ep_tiles, lv_tiles)
     out = (ids.reshape(-1, cfg.topk)[:b], dists.reshape(-1, cfg.topk)[:b])
     if not with_stats:
         return out
     stats = {
         "work": jnp.sum(lane_work.reshape(-1)[:b]),
+        "visited_lost": jnp.sum(lane_lost.reshape(-1)[:b]),
         "launched": jnp.sum(tile_iters) * tile_b,
         "tiles": q_tiles.shape[0],
         "tile_lanes": tile_b,

@@ -115,8 +115,9 @@ def search_tiled_corpus(x, g, queries, eps, cfg, tile_b, mesh,
     if b == 0:
         out = (jnp.zeros((0, cfg.topk), jnp.int32), jnp.zeros((0, cfg.topk)))
         if with_stats:
-            return out + ({"work": jnp.int32(0), "launched": jnp.int32(0),
-                           "tiles": 0, "tile_lanes": 0},)
+            return out + ({"work": jnp.int32(0), "visited_lost": jnp.int32(0),
+                           "launched": jnp.int32(0), "tiles": 0,
+                           "tile_lanes": 0},)
         return out
 
     # lanes: super-tiles of n_dev * tile_b queries, device s owning block s.
@@ -300,11 +301,11 @@ def search_tiled_corpus(x, g, queries, eps, cfg, tile_b, mesh,
 
         _, outs = jax.lax.scan(step, gather_tile(0),
                                jnp.arange(t_count))
-        return outs   # ids (T, tile_b, topk), dists, work (T, tile_b), (T,)
+        return outs   # ids (T, tile_b, topk), dists, work, lost (T, tile_b), (T,)
 
-    ids, dists, lane_work, tile_iters = jax.shard_map(
+    ids, dists, lane_work, lane_lost, tile_iters = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=tuple(specs),
-        out_specs=(lane3, lane3, lane2, P()),
+        out_specs=(lane3, lane3, lane2, lane2, P()),
         check_vma=False,
     )(*operands)
     out = (ids.reshape(-1, cfg.topk)[:b], dists.reshape(-1, cfg.topk)[:b])
@@ -312,6 +313,7 @@ def search_tiled_corpus(x, g, queries, eps, cfg, tile_b, mesh,
         return out
     stats = {
         "work": jnp.sum(lane_work.reshape(-1)[:b]),
+        "visited_lost": jnp.sum(lane_lost.reshape(-1)[:b]),
         "launched": jnp.sum(tile_iters) * ba,
         "tiles": t_count,
         "tile_lanes": ba,

@@ -249,6 +249,26 @@ class TestDisabledNoOp:
         assert "rnn_descent/sweep" in names
         assert "search/tiled" in names
 
+    def test_search_stats_fold_into_registry(self, tiny):
+        """With obs on, ``search_tiled(with_stats=True)`` adds its lane work
+        and its lost visited-table inserts to the process registry."""
+        x, q = tiny
+        g = rd.build(x, CFG, jax.random.PRNGKey(0))
+        eps = S.default_entry_point(x, SCFG.metric)
+        cfg = S.SearchConfig(l=24, k=16, max_iters=64, topk=10, slots=16,
+                             probes=2)
+        with T.enabled_scope():
+            *_, st = S.search_tiled(x, g, q, eps, cfg, tile_b=32,
+                                    with_stats=True)
+        snap = metrics.REGISTRY.snapshot()
+
+        def total(name):
+            return sum(s["value"] for s in snap[name]["samples"])
+
+        assert int(st["visited_lost"]) > 0
+        assert total("search_lane_work_total") == int(st["work"])
+        assert total("search_visited_lost_total") == int(st["visited_lost"])
+
 
 # ------------------------------------------------- on the profiler's clock
 def _host_event_names(trace_dir) -> set:

@@ -24,19 +24,125 @@ def _corpus(metric="l2", seed=0, n=400, d=24, nq=24):
 
 
 # ------------------------------------------------- hashed vs dense equivalence
-@pytest.mark.parametrize("metric", ["l2", "ip", "cos"])
-@pytest.mark.parametrize("seed", [0, 1])
-def test_hashed_matches_dense_oracle(metric, seed):
+@pytest.mark.parametrize("seed, metric, slots, probes", [
+    *[pytest.param(seed, metric, None, 8, id=f"{seed}-{metric}")
+      for seed in (0, 1) for metric in ("l2", "ip", "cos")],
+    # under 128 slots one bucket row (W = slots) holds the lane's table
+    pytest.param(0, "l2", 64, 8, id="0-l2-slots64"),
+    # 16 slots probed twice: windows fill and inserts are lost
+    pytest.param(1, "l2", 16, 2, id="1-l2-overflow"),
+])
+def test_hashed_matches_dense_oracle(seed, metric, slots, probes):
     """With a generous iteration budget the hashed table's only failure mode
     (lost insertions -> re-scoring) cannot change the converged beam, so
-    results must match the exact dense bitmask bit-for-bit."""
+    results must match the exact dense bitmask bit-for-bit, with the same
+    beam expansions."""
     x, q, g = _corpus(metric=metric, seed=seed)
     ep = S.default_entry_point(x, metric)
     base = dict(l=16, k=12, max_iters=128, metric=metric, topk=5)
-    ids_h, d_h = S.search(x, g, q, ep, S.SearchConfig(visited="hashed", **base))
-    ids_d, d_d = S.search(x, g, q, ep, S.SearchConfig(visited="dense", **base))
+    hashed = S.SearchConfig(visited="hashed", slots=slots, probes=probes,
+                            **base)
+    dense = S.SearchConfig(visited="dense", **base)
+    ids_h, d_h = S.search(x, g, q, ep, hashed)
+    ids_d, d_d = S.search(x, g, q, ep, dense)
     np.testing.assert_array_equal(np.asarray(ids_h), np.asarray(ids_d))
     np.testing.assert_allclose(np.asarray(d_h), np.asarray(d_d), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(d_h).view(np.uint32),
+                                  np.asarray(d_d).view(np.uint32))
+    *_, st_h = S.search_tiled(x, g, q, ep, hashed, tile_b=q.shape[0],
+                              with_stats=True)
+    *_, st_d = S.search_tiled(x, g, q, ep, dense, tile_b=q.shape[0],
+                              with_stats=True)
+    assert int(st_h["work"]) == int(st_d["work"])
+    assert int(st_d["visited_lost"]) == 0
+    if slots == 16:
+        assert int(st_h["visited_lost"]) > 0
+
+
+# ----------------------------------------------- bucket-row table, directly
+_visited_lookup_insert = jax.jit(S._visited_lookup_insert,
+                                 static_argnames="probes")
+
+
+def _lookup_insert(table, ids, want=None, probes=8):
+    ids = jnp.asarray(ids, jnp.int32)
+    want = jnp.ones(ids.shape, bool) if want is None else jnp.asarray(want)
+    return _visited_lookup_insert(table, ids, want, jnp.arange(ids.shape[0]),
+                                  probes=probes)
+
+
+def _table(lanes, slots):
+    w = min(128, slots)
+    return jnp.full((lanes * slots // w, w), -1, jnp.int32)
+
+
+@pytest.mark.parametrize("slots, probes", [(256, 8), (64, 2), (8, 8)])
+def test_visited_table_no_false_positive(slots, probes):
+    """Inserted ids are found again unless their insert was lost; ids never
+    inserted are never reported seen, however full the table gets."""
+    rng = np.random.default_rng(slots)
+    lanes, c = 4, 16
+    pool = rng.permutation(1 << 20)[: 2 * 8 * c * lanes].reshape(2, lanes, -1)
+    inserted, absent = pool[0], pool[1]
+    table, lost = _table(lanes, slots), 0
+    for step in range(8):
+        ids = inserted[:, step * c:(step + 1) * c]
+        seen, table, n_lost = _lookup_insert(table, ids, probes=probes)
+        assert not np.asarray(seen).any()    # fresh ids: never seen before
+        lost += int(np.sum(np.asarray(n_lost)))
+    seen_in, _, _ = _lookup_insert(table, inserted, want=np.zeros(
+        inserted.shape, bool), probes=probes)
+    seen_out, _, _ = _lookup_insert(table, absent, want=np.zeros(
+        absent.shape, bool), probes=probes)
+    assert not np.asarray(seen_out).any()
+    stored = np.asarray(table)
+    assert int(np.asarray(seen_in).sum()) == int((stored >= 0).sum())
+    assert int(np.asarray(seen_in).sum()) + lost == inserted.size
+
+
+def test_visited_table_reinsert_found():
+    table = _table(2, 4096)
+    ids = [[7, 1 << 20], [12345, 3]]
+    seen, table, lost = _lookup_insert(table, ids)
+    assert not np.asarray(seen).any() and int(np.sum(lost)) == 0
+    seen, table2, lost = _lookup_insert(table, ids)
+    assert np.asarray(seen).all() and int(np.sum(lost)) == 0
+    np.testing.assert_array_equal(np.asarray(table2), np.asarray(table))
+    # lanes are separate tables: lane 1 has not seen lane 0's ids
+    seen, _, _ = _lookup_insert(table, [[12345, 3], [7, 1 << 20]],
+                                want=np.zeros((2, 2), bool))
+    assert not np.asarray(seen).any()
+
+
+def test_visited_table_full_window_drops_and_counts():
+    """A probe window with no empty slot drops the insert, counts it lost
+    and leaves the table as it was."""
+    table = jnp.arange(100, 108, dtype=jnp.int32)[None, :]   # 1 lane, 8 slots
+    seen, table2, lost = _lookup_insert(table, [[5, 6]], probes=3)
+    assert not np.asarray(seen).any()
+    assert np.asarray(lost).tolist() == [2]
+    np.testing.assert_array_equal(np.asarray(table2), np.asarray(table))
+    # a candidate that was already seen, or not wanted, is never lost
+    seen, _, lost = _lookup_insert(table, [[100, 5]], want=[[True, False]],
+                                   probes=8)
+    assert np.asarray(seen).tolist() == [[True, False]]
+    assert np.asarray(lost).tolist() == [0]
+
+
+def test_visited_table_same_bucket_writes_keep_earlier_entries():
+    """Two fresh ids landing in one bucket in one step: one row write wins,
+    the other insert is counted lost, and no earlier entry is erased."""
+    table = _table(1, 8)                                      # one bucket
+    for earlier in (41, 42):
+        _, table, lost = _lookup_insert(table, [[earlier]])
+        assert np.asarray(lost).tolist() == [0]
+    seen, table, lost = _lookup_insert(table, [[43, 44]])
+    assert not np.asarray(seen).any()
+    assert np.asarray(lost).tolist() == [1]
+    seen, _, _ = _lookup_insert(table, [[41, 42, 43, 44]],
+                                want=np.zeros((1, 4), bool))
+    seen = np.asarray(seen)[0]
+    assert seen[:2].all() and seen[2:].sum() == 1
 
 
 def test_hashed_tiny_table_still_sorted_unique():

@@ -198,6 +198,7 @@ def test_search_sharded_no_padding_blowup(corpus, mesh, rnn_graph):
     *_, st_m = S.search_tiled(x, rnn_graph, q, ep, cfg, tile_b=256,
                               mesh=mesh, with_stats=True)
     assert int(st_1["work"]) == int(st_m["work"])
+    assert int(st_1["visited_lost"]) == int(st_m["visited_lost"])
     assert int(st_m["launched"]) <= int(st_1["launched"])
     # lanes bounded by one ceil-division tile per device
     d = jax.device_count()
@@ -210,8 +211,8 @@ def test_search_corpus_sharded_parity(corpus, mesh, rnn_graph, visited):
     """shard="corpus" — x and adjacency rows partitioned over the mesh,
     frontier gathers routed through owner-contribute collectives — must be
     bitwise equal to the single-device beam: same ids, same uint32 dist
-    bits, same per-lane work. The batch (101) divides neither the tile nor
-    the device count."""
+    bits, same per-lane work and lost visited-table inserts. The batch (101)
+    divides neither the tile nor the device count."""
     x, q = corpus
     cfg = S.SearchConfig(l=16, k=12, max_iters=48, topk=5, visited=visited)
     ep = S.default_entry_point(x)
@@ -224,6 +225,7 @@ def test_search_corpus_sharded_parity(corpus, mesh, rnn_graph, visited):
     assert np.array_equal(np.asarray(G.dist_key(d_1)),
                           np.asarray(G.dist_key(d_m)))
     assert int(st_1["work"]) == int(st_m["work"])
+    assert int(st_1["visited_lost"]) == int(st_m["visited_lost"])
     # no lane blowup: the super-tiles launch no more lanes than the
     # single-device tiling of the same batch
     assert st_m["tiles"] * st_m["tile_lanes"] <= st_1["tiles"] * st_1["tile_lanes"]

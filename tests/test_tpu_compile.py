@@ -14,12 +14,16 @@ process at a time may load the TPU library, and every pytest worker imports
 this file.
 """
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import graph as G
+from repro.core import search as S
 from repro.kernels.beam_score import ops as beam_ops
 from repro.kernels.rng_prune import ops as prune_ops
 
@@ -91,3 +95,56 @@ def test_beam_score_pq_refuses_compiled_backend():
             jnp.zeros((n, mq), jnp.uint8), jnp.zeros((n, cap), jnp.int32),
             jnp.zeros((b,), jnp.int32), jnp.zeros((b, mq, 256)),
             jnp.zeros((mq, 256)), jnp.zeros((b,)), k=8, interpret=False)
+
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9-]*)\(")
+_SHAPE = re.compile(r"[a-z][a-z0-9]*\[([0-9,]*)\]")
+
+
+def _ops(hlo: str):
+    """(opcode, [element count of each result shape], line) per HLO op."""
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m:
+            sizes = [math.prod(int(v) for v in dims.split(",") if v)
+                     for dims in _SHAPE.findall(m.group(1))]
+            yield m.group(2), sizes, line
+
+
+def test_search_visited_table_stays_in_place(one_chip):
+    """The beam's hashed visited table at the GIST cell's shape (256-lane
+    tile, L=64, k=64, max_iters=256: 65,536 slots a lane, 16,777,216 int32
+    a tile) is probed by one 128-slot row gather per candidate and written
+    back by row, in place: no whole-table copy, slice or relayout, and no
+    gather of single table elements."""
+    n, d, cap, b = 4096, 128, 128, 256
+    cfg = S.SearchConfig(l=64, k=64, max_iters=256, topk=10,
+                         visited="hashed")
+    slots = S.resolve_slots(cfg)
+    table = b * slots
+    assert table == 16_777_216
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    g = G.Graph(sds((n, cap), jnp.int32), sds((n, cap), jnp.float32),
+                sds((n, cap), jnp.uint8))
+    hlo = S._search_tiled_jit.lower(
+        sds((n, d), jnp.float32), g, sds((b, d), jnp.float32),
+        sds((), jnp.int32), cfg, b, None, sds((n,), jnp.bool_), None,
+        "queries", True, None).compile().as_text()
+    ops = list(_ops(hlo))
+    assert ops
+    moved = [line for op, sizes, line in ops
+             if op in ("copy", "copy-start", "slice-start", "reshape")
+             and table in sizes]
+    assert not moved, moved[:3]
+    scalar_gathers = [line for op, sizes, line in ops
+                      if op == "gather" and b * cfg.k * cfg.probes in sizes
+                      and re.search(r"slice_sizes=\{1(,1)*\}", line)]
+    assert not scalar_gathers, scalar_gathers[:3]
+    probe = [line for op, sizes, line in ops
+             if op == "gather" and b * cfg.k * 128 in sizes
+             and "slice_sizes={1,128}" in line
+             and line.lstrip().split(" = ")[1].startswith("s32[")]
+    assert probe

@@ -49,6 +49,43 @@ def ground_truth(
     return D.pairwise_tiled(queries, x, metric, tile_a=tile, k=k)
 
 
+def filtered_ground_truth(
+    x, queries, labels, filters, k: int = 1, metric: str = "l2",
+    valid=None,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Exact top-k under a per-query label predicate: the plain reference of
+    ``search(labels=, filters=)``. Brute force on the host in float64, one
+    query at a time, over the rows whose label word holds every bit of the
+    query's mask (``(label & mask) == mask``) and, with ``valid``, that are
+    live. No graph, tiles or batching. Returns (dists, ids), (B, k) numpy,
+    nearest first, padded (+inf, -1) when fewer than k rows pass."""
+    x = np.asarray(x, np.float64)
+    q = np.asarray(queries, np.float64)
+    lab = np.asarray(labels).astype(np.uint32)
+    masks = np.asarray(filters).astype(np.uint32)
+    live = np.ones(x.shape[0], bool) if valid is None else np.asarray(valid)
+    if metric == "cos":
+        x = x / np.maximum(np.linalg.norm(x, axis=1, keepdims=True), 1e-12)
+        q = q / np.maximum(np.linalg.norm(q, axis=1, keepdims=True), 1e-12)
+    out_d = np.full((q.shape[0], k), np.inf)
+    out_i = np.full((q.shape[0], k), -1, np.int64)
+    for i, (qi, m) in enumerate(zip(q, masks)):
+        if metric == "l2":
+            d = np.sum((x - qi) ** 2, axis=1)
+        elif metric == "ip":
+            d = -(x @ qi)
+        elif metric == "cos":
+            d = 1.0 - x @ qi
+        else:
+            raise ValueError(f"unknown metric {metric!r}")
+        d = np.where(((lab & m) == m) & live, d, np.inf)
+        order = np.argsort(d, kind="stable")[:k]
+        ok = np.isfinite(d[order])
+        out_d[i, :ok.sum()] = d[order][ok]
+        out_i[i, :ok.sum()] = order[ok]
+    return out_d, out_i
+
+
 def recall_at_k(pred_ids: jnp.ndarray, gt_ids: jnp.ndarray) -> float:
     """Fraction of queries whose true NN (gt column 0) appears in pred."""
     hit = jnp.any(pred_ids == gt_ids[:, :1], axis=1)

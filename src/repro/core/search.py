@@ -94,8 +94,26 @@ interchangeable implementations selected by ``SearchConfig.use_pallas``
 Each iteration's phases run under ``jax.named_scope`` names —
 ``beam.select`` (frontier pick, retirement), ``beam.score`` (gather +
 score), ``beam.visited`` (dedup + visited table), ``beam.topk`` (beam
-merge) — which the compiled ops carry as metadata into a device trace; a
-scope adds no op, so results are unchanged.
+merge), and in a filtered search ``beam.filter`` (label gather, predicate,
+result-list merge) — which the compiled ops carry as metadata into a device
+trace; a scope adds no op, so results are unchanged.
+
+Filtered search
+---------------
+``labels=`` gives each corpus row one ``uint32`` label word (a bitset of up
+to 32 labels) and ``filters=`` each query a ``uint32`` mask; a row passes
+its query when ``(label & mask) == mask``, so a mask of several bits is a
+conjunction. The predicate is checked inside the loop, hnswlib-style
+(:func:`_filtered_search_impl`): a candidate list of the L nearest
+unexpanded vertices drives the expansion, passing or not (non-passing
+vertices still connect the graph), and an expanded vertex leaves it; a
+result list keeps the L nearest passing vertices. A lane retires when its
+result list is full and its best candidate lies beyond the worst result,
+when its candidates run out, or at ``max_iters``. With ``filters=None`` the
+traced program is the unfiltered one above (a static branch: no label
+gather, no second list). Because expanded vertices leave the candidate
+list, a vertex scored before may lie nearer than every listed one: the
+visited table is what keeps it from being expanded again.
 """
 from __future__ import annotations
 
@@ -283,6 +301,52 @@ def _visited_lookup_insert(
     return seen, table, lost
 
 
+def _seed_visited(eps, dup, rows, n: int, slots: int, probes: int,
+                  dense: bool):
+    """(visited, lost): the visited state of a tile with its seeds marked."""
+    b = rows.shape[0]
+    if dense:
+        visited = jnp.zeros((b, n + 1), bool)
+        visited = visited.at[rows[:, None], jnp.where(dup, n, eps)].set(True)
+        return visited, jnp.zeros((b,), jnp.int32)
+    w = _bucket_width(slots)
+    visited = jnp.full((b * slots // w, w), -1, jnp.int32)
+    _, visited, lost = _visited_lookup_insert(visited, eps, ~dup, rows, probes)
+    return visited, lost
+
+
+def _visit(visited, nbrs, cand_ok, listed, rows, n: int, probes: int,
+           dense: bool, lost):
+    """One ``beam.visited`` step over a (B, C) block of scored neighbours:
+    (fresh, visited, lost), ``fresh`` marking the candidates not seen
+    before. Dense: the exact bitmask. Hashed: the table, backed by an exact
+    dedup against the (B, L) id lists in ``listed`` — a lost insertion can
+    cost a re-score, never a duplicate in a list."""
+    if dense:
+        seen = visited[rows[:, None], jnp.maximum(nbrs, 0)]
+        fresh = cand_ok & ~seen
+        ins_idx = jnp.where(fresh, nbrs, n)                   # n = scratch slot
+        visited = visited.at[rows[:, None], ins_idx].set(True)
+        return fresh, visited, lost
+    in_list = functools.reduce(jnp.logical_or, [
+        jnp.any(nbrs[:, :, None] == ids[:, None, :], axis=-1)
+        for ids in listed])
+    seen, visited, lost_now = _visited_lookup_insert(
+        visited, nbrs, cand_ok & ~in_list, rows, probes)
+    lost = lost + lost_now
+    return cand_ok & ~seen & ~in_list, visited, lost
+
+
+def _seed_dups(eps):
+    """(B, E) bool: a seed repeating an earlier seed of its lane (inert)."""
+    e = eps.shape[1]
+    return jnp.any(
+        (eps[:, :, None] == eps[:, None, :])
+        & (jnp.arange(e)[None, :, None] > jnp.arange(e)[None, None, :]),
+        axis=-1,
+    )
+
+
 # ------------------------------------------------------------ entry validation
 def _validate_entry_points(entry_points, b: int, l: int) -> jnp.ndarray:
     """Normalize ``entry_points`` to (B, E) int32.
@@ -384,11 +448,7 @@ def _search_impl(
     # inert. Under int8/pq the seeds score through the *quantized* corpus —
     # every beam distance lives on one scale, so candidate/seed comparisons
     # stay meaningful and the rerank tail restores exactness at the end.
-    dup = jnp.any(
-        (eps[:, :, None] == eps[:, None, :])
-        & (jnp.arange(e)[None, :, None] > jnp.arange(e)[None, None, :]),
-        axis=-1,
-    )
+    dup = _seed_dups(eps)
     if hooks is not None:
         ep_d = hooks.seed(eps)                                    # (B, E)
     elif qmode == "int8":
@@ -409,15 +469,7 @@ def _search_impl(
     beam_ids = jnp.take_along_axis(beam_ids, order, axis=1)
     expanded = jnp.take_along_axis(expanded, order, axis=1)
 
-    if dense:
-        visited = jnp.zeros((b, n + 1), bool)
-        visited = visited.at[rows[:, None], jnp.where(dup, n, eps)].set(True)
-        lost = jnp.zeros((b,), jnp.int32)
-    else:
-        w = _bucket_width(slots)
-        visited = jnp.full((b * slots // w, w), -1, jnp.int32)
-        _, visited, lost = _visited_lookup_insert(
-            visited, eps, ~dup, rows, cfg.probes)
+    visited, lost = _seed_visited(eps, dup, rows, n, slots, cfg.probes, dense)
 
     # padded lanes (query-count padding in search_tiled) start retired: they
     # never expand, never score, and a tile made entirely of padding exits
@@ -490,19 +542,8 @@ def _search_impl(
         # distinct from the function-level `valid` tombstone mask
         with jax.named_scope("beam.visited"):
             cand_ok = (nbrs >= 0) & active[:, None]
-            if dense:
-                seen = visited[rows[:, None], jnp.maximum(nbrs, 0)]
-                fresh = cand_ok & ~seen
-                ins_idx = jnp.where(fresh, nbrs, n)                   # n = scratch slot
-                visited = visited.at[rows[:, None], ins_idx].set(True)
-            else:
-                # exact candidate-vs-beam dedup backs up the lossy hash table:
-                # a lost insertion can cost a re-score, never a duplicate result
-                in_beam = jnp.any(nbrs[:, :, None] == beam_ids[:, None, :], axis=-1)
-                seen, visited, lost_now = _visited_lookup_insert(
-                    visited, nbrs, cand_ok & ~in_beam, rows, cfg.probes)
-                lost = lost + lost_now
-                fresh = cand_ok & ~seen & ~in_beam
+            fresh, visited, lost = _visit(visited, nbrs, cand_ok, (beam_ids,),
+                                          rows, n, cfg.probes, dense, lost)
 
         with jax.named_scope("beam.topk"):
             nd = jnp.where(fresh, cand_d, jnp.inf)
@@ -561,6 +602,134 @@ def _search_impl(
     return beam_ids[:, : cfg.topk], beam_d[:, : cfg.topk], work, lost, iters
 
 
+def _filtered_search_impl(
+    x: jnp.ndarray,
+    g: G.Graph,
+    queries: jnp.ndarray,
+    eps: jnp.ndarray,            # (B, E) validated
+    cfg: SearchConfig,
+    labels: jnp.ndarray,         # (n,) uint32 label word per row
+    filters: jnp.ndarray,        # (B,) uint32 mask per query
+    valid: jnp.ndarray | None = None,
+    lane_valid: jnp.ndarray | None = None,
+):
+    """Beam search under a per-query label predicate (module docstring,
+    "Filtered search"). Returns (ids, dists, work, lost, iters, scored,
+    passed): as :func:`_search_impl`, plus per lane the fresh candidates
+    scored and those of them that passed (live and matching the mask)."""
+    n = x.shape[0]
+    b = queries.shape[0]
+    k = min(cfg.k, g.capacity)
+    rows = jnp.arange(b)
+    dense = cfg.visited == "dense"
+    want = filters[:, None]
+
+    def passes(ids):
+        safe = jnp.maximum(ids, 0)
+        ok = (ids >= 0) & ((labels[safe] & want) == want)
+        return ok if valid is None else ok & valid[safe]
+
+    def merge(ids, d, new_ids, new_d):
+        # the L nearest of a (B, L) list and a (B, C) block, ascending
+        neg_d, order = jax.lax.top_k(-jnp.concatenate([d, new_d], axis=1),
+                                     cfg.l)
+        all_ids = jnp.concatenate([ids, new_ids], axis=1)
+        return jnp.take_along_axis(all_ids, order, axis=1), -neg_d
+
+    dup = _seed_dups(eps)
+    seed_ids = jnp.where(dup, -1, eps)
+    seed_d = jnp.where(dup, jnp.inf, score_block(x[eps], queries, cfg.metric))
+    no_ids = jnp.full((b, cfg.l), -1, jnp.int32)
+    no_d = jnp.full((b, cfg.l), jnp.inf)
+    cand_ids, cand_d = merge(no_ids, no_d, seed_ids, seed_d)
+    ok = passes(seed_ids)
+    res_ids, res_d = merge(no_ids, no_d, jnp.where(ok, seed_ids, -1),
+                           jnp.where(ok, seed_d, jnp.inf))
+    visited, lost = _seed_visited(eps, dup, rows, n, resolve_slots(
+        cfg, eps.shape[1]), cfg.probes, dense)
+    done = jnp.zeros((b,), bool) if lane_valid is None else ~lane_valid
+    zeros = jnp.zeros((b,), jnp.int32)
+
+    def cond(state):
+        return jnp.logical_and(state[6] < cfg.max_iters, state[-1])
+
+    def body(state):
+        (cand_ids, cand_d, res_ids, res_d, visited, done, it, work, lost,
+         scored, passed, _) = state
+        with jax.named_scope("beam.select"):
+            # both lists are top_k-sorted: column 0 is the best candidate,
+            # column L-1 the worst result (+inf until L passing were found)
+            best, worst = cand_d[:, 0], res_d[:, -1]
+            done = (done | ~jnp.isfinite(best)
+                    | (jnp.isfinite(worst) & (best > worst)))
+            active = ~done
+            work = work + active.astype(jnp.int32)
+            u = jnp.where(active, cand_ids[:, 0], 0)
+            # the expanded vertex leaves the candidate list
+            cand_ids = cand_ids.at[:, 0].set(
+                jnp.where(active, -1, cand_ids[:, 0]))
+            cand_d = cand_d.at[:, 0].set(jnp.where(active, jnp.inf, best))
+        with jax.named_scope("beam.score"):
+            if cfg.use_pallas:
+                nbrs, nd, _ = beam_score(
+                    x, g.neighbors, u, queries, k=k, metric=cfg.metric,
+                    tile_b=cfg.kernel_tile_b,
+                    gram_dtype=cfg.effective_gram_dtype)
+            else:
+                nbrs, nd, _ = beam_score_ref(
+                    x, g.neighbors, u, queries, k=k, metric=cfg.metric,
+                    gram_dtype=cfg.effective_gram_dtype)
+        with jax.named_scope("beam.visited"):
+            fresh, visited, lost = _visit(
+                visited, nbrs, (nbrs >= 0) & active[:, None],
+                (cand_ids, res_ids), rows, n, cfg.probes, dense, lost)
+        with jax.named_scope("beam.filter"):
+            ok = fresh & passes(nbrs)
+            scored = scored + jnp.sum(fresh, axis=1, dtype=jnp.int32)
+            passed = passed + jnp.sum(ok, axis=1, dtype=jnp.int32)
+            res_ids, res_d = merge(res_ids, res_d, jnp.where(ok, nbrs, -1),
+                                   jnp.where(ok, nd, jnp.inf))
+        with jax.named_scope("beam.topk"):
+            cand_ids, cand_d = merge(cand_ids, cand_d,
+                                     jnp.where(fresh, nbrs, -1),
+                                     jnp.where(fresh, nd, jnp.inf))
+        return (cand_ids, cand_d, res_ids, res_d, visited, done, it + 1,
+                work, lost, scored, passed, jnp.any(~done))
+
+    state = (cand_ids, cand_d, res_ids, res_d, visited, done, jnp.int32(0),
+             zeros, lost, zeros, zeros, jnp.any(~done))
+    _, _, res_ids, res_d, _, _, iters, work, lost, scored, passed, _ = \
+        jax.lax.while_loop(cond, body, state)
+    d = res_d[:, : cfg.topk]
+    ids = jnp.where(jnp.isfinite(d), res_ids[:, : cfg.topk], -1)
+    return ids, d, work, lost, iters, scored, passed
+
+
+def _check_filters(x, queries, cfg: SearchConfig, labels, filters,
+                   shard: str = "queries") -> None:
+    """The combinations a filtered search supports, and the shapes it
+    needs; raises ValueError otherwise."""
+    if labels is None:
+        raise ValueError(
+            "filters= needs labels=: one uint32 label word per corpus row")
+    if shard == "corpus":
+        raise ValueError(
+            "filters= is not supported with shard=\"corpus\": the "
+            "corpus-sharded beam has no label store")
+    if cfg.quant.is_coded:
+        raise ValueError(
+            f"filters= is not supported with quant mode "
+            f"{cfg.quant.mode!r}: the filtered beam scores the f32 corpus")
+    if labels.shape != (x.shape[0],):
+        raise ValueError(
+            f"labels has shape {labels.shape}, expected ({x.shape[0]},): "
+            "one label word per corpus row")
+    if filters.shape != (queries.shape[0],):
+        raise ValueError(
+            f"filters has shape {filters.shape}, expected "
+            f"({queries.shape[0]},): one mask per query")
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def search(
     x: jnp.ndarray,
@@ -570,6 +739,8 @@ def search(
     cfg: SearchConfig,
     valid: jnp.ndarray | None = None,
     qx: QuantizedCorpus | None = None,
+    labels: jnp.ndarray | None = None,
+    filters: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (ids, dists) of shape (B, topk), ascending distance.
 
@@ -581,8 +752,18 @@ def search(
     ``qx``: the encoded corpus (:func:`repro.quant.encode_corpus`) — required
     when ``cfg.quant`` selects int8/pq; the beam then gathers codes and ``x``
     is touched only by the exact rerank tail.
+    ``labels`` (n,) / ``filters`` (B,): a per-query label predicate, checked
+    inside the beam (module docstring, "Filtered search"); rows that fail
+    it are traversed but never returned. ``filters=None`` ignores
+    ``labels``.
     """
     eps = _validate_entry_points(entry_points, queries.shape[0], cfg.l)
+    if filters is not None:
+        _check_filters(x, queries, cfg, labels, filters)
+        ids, dists, *_ = _filtered_search_impl(
+            x, g, queries, eps, cfg, labels.astype(jnp.uint32),
+            filters.astype(jnp.uint32), valid=valid)
+        return ids, dists
     ids, dists, *_ = _search_impl(x, g, queries, eps, cfg, valid=valid,
                                   qx=qx)
     return ids, dists
@@ -601,6 +782,8 @@ def search_tiled(
     shard: str = "queries",
     with_stats: bool = False,
     lane_valid: jnp.ndarray | None = None,
+    labels: jnp.ndarray | None = None,
+    filters: jnp.ndarray | None = None,
 ):
     """Stream an arbitrary query count through B_tile-sized ``lax.map`` tiles.
 
@@ -651,6 +834,15 @@ def search_tiled(
     (lanes never interact — the admission determinism contract in
     tests/test_serving.py).
 
+    ``labels`` (n,) uint32 / ``filters`` (B,) uint32: a per-query label
+    predicate checked inside the beam (see :func:`search`); composes with
+    ``valid`` (a result must be live and pass) and with ``mesh`` under
+    ``shard="queries"``. ``shard="corpus"`` and coded ``cfg.quant`` raise
+    ValueError with ``filters``. ``with_stats`` then adds "filter_scored"
+    (fresh candidates scored), "filter_passed" (those that passed),
+    "work_max" (the most expansions of any lane) and "capped" (lanes whose
+    expansions reached ``max_iters``).
+
     Returns (ids, dists), plus the stats dict when ``with_stats``.
 
     Observability: this host wrapper dispatches to one jitted program
@@ -666,8 +858,10 @@ def search_tiled(
     program with or without tracing.
     """
     from repro.obs import trace as _tr
+    if filters is None:
+        labels = None    # the unfiltered program takes no label operand
     args = (x, g, queries, entry_points, cfg, tile_b, mesh, valid, qx, shard,
-            with_stats, lane_valid)
+            with_stats, lane_valid, labels, filters)
     if isinstance(queries, jax.core.Tracer):
         return _search_tiled_jit(*args)
     with _tr.span("search/tiled") as sp:
@@ -700,6 +894,16 @@ def search_tiled(
                              "(includes padded/retired lanes)").inc(launched)
             reg.counter("search_tiles_total",
                         help="search tiles dispatched").inc(tiles)
+            if "filter_scored" in stats:
+                scored = int(stats["filter_scored"])
+                passed = int(stats["filter_passed"])
+                sp.set(filter_scored=scored, filter_passed=passed)
+                reg.counter("search_filter_scored_total",
+                            help="fresh candidates scored by filtered "
+                                 "searches").inc(scored)
+                reg.counter("search_filter_passed_total",
+                            help="scored candidates that passed their "
+                                 "query's predicate").inc(passed)
     return out
 
 
@@ -718,6 +922,8 @@ def _search_tiled_jit(
     shard: str = "queries",
     with_stats: bool = False,
     lane_valid: jnp.ndarray | None = None,
+    labels: jnp.ndarray | None = None,
+    filters: jnp.ndarray | None = None,
 ):
     if shard not in ("queries", "corpus"):
         raise ValueError(
@@ -729,6 +935,9 @@ def _search_tiled_jit(
         check_auto(mesh)
     b = queries.shape[0]
     eps = _validate_entry_points(entry_points, b, cfg.l)
+    filtered = filters is not None
+    if filtered:
+        _check_filters(x, queries, cfg, labels, filters, shard)
     if lane_valid is not None and lane_valid.shape != (b,):
         raise ValueError(
             f"lane_valid has shape {lane_valid.shape} but the query batch "
@@ -771,8 +980,20 @@ def _search_tiled_jit(
     if lane_valid is not None:
         lv = lv & jnp.pad(lane_valid.astype(bool), (0, pad))
     lv_tiles = lv.reshape(-1, tile_b)
+    # a filtered search adds the label store and one mask tile per query tile
+    filt = (labels.astype(jnp.uint32),
+            jnp.pad(filters.astype(jnp.uint32), (0, pad)).reshape(-1, tile_b)
+            ) if filtered else ()
 
-    def tiles_body(xx, gg, vv, qq, qt, et, lt):
+    def tiles_body(xx, gg, vv, qq, qt, et, lt, *lab_ft):
+        if lab_ft:
+            lab, ft = lab_ft
+            return jax.lax.map(
+                lambda t: _filtered_search_impl(
+                    xx, gg, t[0], t[1], cfg, lab, t[3], valid=vv,
+                    lane_valid=t[2]),
+                (qt, et, lt, ft),
+            )
         return jax.lax.map(
             lambda t: _search_impl(xx, gg, t[0], t[1], cfg, valid=vv, qx=qq,
                                    lane_valid=t[2]),
@@ -798,8 +1019,10 @@ def _search_tiled_jit(
         if has_qx:
             operands.append(qx)
             specs.append(jax.tree.map(lambda _: P(), qx))
-        operands += [q_tiles, ep_tiles, lv_tiles]
-        specs += [qspec, qspec, SH.pspec(mesh, "queries", None)]
+        lane_spec = SH.pspec(mesh, "queries", None)
+        operands += [q_tiles, ep_tiles, lv_tiles, *filt]
+        specs += [qspec, qspec, lane_spec] + ([P(), lane_spec] if filtered
+                                              else [])
 
         def dispatch(xx, gg, *rest):
             i = 0
@@ -807,20 +1030,19 @@ def _search_tiled_jit(
             i += has_valid
             qq = rest[i] if has_qx else None
             i += has_qx
-            return tiles_body(xx, gg, vv, qq, rest[i], rest[i + 1],
-                              rest[i + 2])
+            return tiles_body(xx, gg, vv, qq, *rest[i:])
 
-        lane_spec = SH.pspec(mesh, "queries", None)
-        ids, dists, lane_work, lane_lost, tile_iters = jax.shard_map(
+        outs = jax.shard_map(
             dispatch, mesh=mesh,
             in_specs=tuple(specs),
             out_specs=(qspec, qspec, lane_spec, lane_spec,
-                       SH.pspec(mesh, "queries")),
+                       SH.pspec(mesh, "queries"))
+            + ((lane_spec, lane_spec) if filtered else ()),
             check_vma=False,
         )(*operands)
     else:
-        ids, dists, lane_work, lane_lost, tile_iters = tiles_body(
-            x, g, valid, qx, q_tiles, ep_tiles, lv_tiles)
+        outs = tiles_body(x, g, valid, qx, q_tiles, ep_tiles, lv_tiles, *filt)
+    ids, dists, lane_work, lane_lost, tile_iters = outs[:5]
     out = (ids.reshape(-1, cfg.topk)[:b], dists.reshape(-1, cfg.topk)[:b])
     if not with_stats:
         return out
@@ -831,6 +1053,13 @@ def _search_tiled_jit(
         "tiles": q_tiles.shape[0],
         "tile_lanes": tile_b,
     }
+    if filtered:
+        scored, passed = (a.reshape(-1)[:b] for a in outs[5:])
+        work = lane_work.reshape(-1)[:b]
+        stats.update(filter_scored=jnp.sum(scored),
+                     filter_passed=jnp.sum(passed),
+                     work_max=jnp.max(work),
+                     capped=jnp.sum(work >= cfg.max_iters))
     return out + (stats,)
 
 

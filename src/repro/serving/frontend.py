@@ -107,8 +107,17 @@ class ServingFrontend:
         self._ep_cache: tuple[int, jax.Array] | None = None
 
     # --------------------------------------------------------------- ingress
-    def submit(self, query, deadline_s: float | None = None) -> int:
-        """Admit one query; returns its request id."""
+    def submit(self, query, deadline_s: float | None = None,
+               filters=None) -> int:
+        """Admit one query; returns its request id. A label predicate
+        (``filters``) is not served here: admission tiles share one search
+        program without per-lane masks, so it raises ValueError rather
+        than return unfiltered rows (``StreamingANN.search(filters=)``
+        runs filtered batches)."""
+        if filters is not None:
+            raise ValueError(
+                "ServingFrontend does not serve filtered queries: call "
+                "StreamingANN.search(queries, cfg, filters=...) instead")
         now = self.clock()
         rid = self.queue.submit(query, now, deadline_s=deadline_s)
         budget = self.cfg.admission.deadline_s if deadline_s is None \

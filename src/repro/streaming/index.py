@@ -17,7 +17,10 @@ enforced by functional purity instead of barriers.
 Serving is tombstone-aware end to end: ``search`` threads the store's
 live-row mask through ``search_tiled(valid=)`` (deleted rows are traversed
 as bridges but never surface; capacity padding is unreachable by
-construction) and seeds entry points from live rows only.
+construction) and seeds entry points from live rows only. Each row also
+carries a uint32 label word (``Store.labels``, set by ``from_corpus`` and
+``insert``, moved by ``compact``); ``search(filters=)`` returns only rows
+whose word holds every bit of their query's mask, checked in the beam.
 
 Mesh composition: ``mesh=`` routes construction through the PR-4 row-sharded
 build, updates through the frontier-sharded exchange in updates.py, and
@@ -86,9 +89,13 @@ class StreamingANN:
     @classmethod
     def from_corpus(cls, x, cfg: U.StreamingConfig | None = None,
                     key: jax.Array | None = None, mesh: Mesh | None = None,
-                    capacity: int | None = None) -> "StreamingANN":
+                    capacity: int | None = None,
+                    labels=None) -> "StreamingANN":
         """Batch-build the initial graph (``rnn_descent.build``, row-sharded
-        over ``mesh`` when given) and wrap it into a padded store."""
+        over ``mesh`` when given) and wrap it into a padded store.
+        ``labels``: optional (n,) uint32 label word per row, what
+        ``search(filters=)`` tests (none held when None: every row reads
+        as 0)."""
         cfg = cfg if cfg is not None else U.StreamingConfig()
         key = key if key is not None else jax.random.PRNGKey(0)
         x = jnp.asarray(x, jnp.float32)
@@ -98,7 +105,7 @@ class StreamingANN:
         # geometry the graph was optimized for.
         qx = (encode_corpus(x, cfg.build.quant)
               if cfg.build.quant.is_coded else None)
-        st = ST.from_built(x, g, capacity=capacity, qx=qx)
+        st = ST.from_built(x, g, capacity=capacity, qx=qx, labels=labels)
         return cls(store=st, cfg=cfg, mesh=mesh)
 
     # -------------------------------------------------------------- queries
@@ -111,7 +118,8 @@ class StreamingANN:
     def search(self, queries, cfg: S.SearchConfig | None = None,
                entry_points=None, tile_b: int = 256,
                shard: str = "queries", with_stats: bool = False,
-               lane_valid=None, store: ST.Store | None = None):
+               lane_valid=None, store: ST.Store | None = None,
+               filters=None):
         """Tombstone-aware serving over the current epoch's snapshot:
         deleted rows route traffic but never appear in the top-k; lanes
         reaching fewer than topk live vertices pad with (-1, +inf).
@@ -123,7 +131,10 @@ class StreamingANN:
         "corpus"`` to serve a row-partitioned store. ``store=`` searches an
         explicit snapshot (from :meth:`snapshot`) instead of re-reading the
         live reference — the seam that pins a dispatched tile to one epoch
-        even while the writer commits."""
+        even while the writer commits. ``filters``: optional (B,) uint32
+        mask per query; a row is returned only when its label word holds
+        every bit of its query's mask (checked inside the beam, see
+        :func:`repro.core.search.search_tiled`)."""
         st = self.store if store is None else store  # one read = one epoch
         cfg = cfg if cfg is not None else S.SearchConfig()
         qx = None
@@ -144,17 +155,23 @@ class StreamingANN:
                 if entry_points is None:
                     entry_points = S.default_entry_point(st.x, cfg.metric,
                                                          valid=valid)
+            labels = None
+            if filters is not None:
+                labels = ST.row_labels(st)
+                filters = jnp.asarray(filters)
             return S.search_tiled(st.x, st.graph, jnp.asarray(queries),
                                   entry_points, cfg, tile_b=tile_b,
                                   mesh=self.mesh, valid=valid, qx=qx,
                                   shard=shard, with_stats=with_stats,
-                                  lane_valid=lane_valid)
+                                  lane_valid=lane_valid, labels=labels,
+                                  filters=filters)
 
     # -------------------------------------------------------------- updates
-    def insert(self, new_x) -> np.ndarray:
+    def insert(self, new_x, labels=None) -> np.ndarray:
         """Insert a batch; returns the assigned row ids. Grows the store
         (power-of-two capacity, a recompile event) when free rows run out,
-        then commits the updated store atomically."""
+        then commits the updated store atomically. ``labels``: optional
+        (B,) uint32 label words of the new rows (0 when None)."""
         new_x = jnp.asarray(new_x, jnp.float32)
         b = int(new_x.shape[0])
         st = self.store
@@ -162,7 +179,8 @@ class StreamingANN:
             st = ST.grow(st, ST.occupied_count(st) + b)
             if self.mesh is not None:
                 st = _place(st, self.mesh)
-        st, slots = U.insert(st, new_x, self.cfg, mesh=self.mesh)
+        st, slots = U.insert(st, new_x, self.cfg, mesh=self.mesh,
+                             labels=labels)
         self.store = st                      # atomic epoch swap
         return slots
 
@@ -261,7 +279,8 @@ class StreamingANN:
             qx_like = None
         like = ST.Store(x=0, graph=G.Graph(0, 0, 0), occupied=0, tombstone=0,
                         epoch=0, qx=qx_like,
-                        remap=0 if ".remap" in names else None)
+                        remap=0 if ".remap" in names else None,
+                        labels=0 if ".labels" in names else None)
         st = checkpoint.restore(ckpt_dir, step, like)
         st = jax.tree.map(jnp.asarray, st)
         if cfg is None:

@@ -22,8 +22,9 @@ store by exactly one batch would recompile every update program on every
 batch. Doubling instead amortizes recompilation to O(log n) growth events,
 at the classic ≤ 2x memory overhead — the same tradeoff as the hashed visited
 table and bucket widths elsewhere in the codebase. Per-row memory is
-``d * 4`` (x) + ``M * 9`` (adjacency fields) + 2 bytes (masks), so a store at
-capacity C carries at most twice the footprint of an exact-fit corpus.
+``d * 4`` (x) + ``M * 9`` (adjacency fields) + 2 bytes (masks), plus 4
+bytes (label word) in a labelled store, so a store at capacity C carries at
+most twice the footprint of an exact-fit corpus.
 
 Everything here is a pure function from Store to Store: updates build a new
 pytree and leave the input untouched, which is what makes the epoch-snapshot
@@ -46,7 +47,10 @@ class Store(NamedTuple):
     qx: optional quantized codes | remap: optional last-compaction remap
     (both trailing, default None, so checkpoints and pytree traversals of
     stores that never held them are unchanged — None fields are leafless
-    under pytree flatten).
+    under pytree flatten) | labels: optional (C,) uint32 label word per row,
+    the bitset a filtered search tests (``search(filters=)``); None until
+    some rows are given labels, 0 in rows given none (such a row passes
+    only the empty mask). Trailing and default None, like qx and remap.
 
     A quantized store keeps *both* representations resident: ``qx.codes``
     serve the coded search (and grow / compact / checkpoint exactly like
@@ -67,6 +71,7 @@ class Store(NamedTuple):
     epoch: jnp.ndarray
     qx: QuantizedCorpus | None = None
     remap: jnp.ndarray | None = None
+    labels: jnp.ndarray | None = None
 
     @property
     def capacity(self) -> int:
@@ -106,6 +111,17 @@ def free_count(store: Store) -> int:
     return store.capacity - occupied_count(store)
 
 
+def row_labels(store: Store) -> jnp.ndarray:
+    """(C,) uint32 label words (zeros where the store holds none)."""
+    if store.labels is None:
+        return jnp.zeros((store.capacity,), jnp.uint32)
+    return store.labels
+
+
+def _pad_labels(labels: jnp.ndarray | None, pad: int) -> jnp.ndarray | None:
+    return None if labels is None else jnp.pad(labels, (0, pad))
+
+
 def _pad_graph(g: G.Graph, cap: int) -> G.Graph:
     n = g.n
     return G.Graph(
@@ -127,10 +143,13 @@ def _pad_codes(qx: QuantizedCorpus | None, pad: int) -> QuantizedCorpus | None:
 
 def from_built(x: jnp.ndarray, g: G.Graph,
                capacity: int | None = None,
-               qx: QuantizedCorpus | None = None) -> Store:
+               qx: QuantizedCorpus | None = None,
+               labels=None) -> Store:
     """Wrap a batch-built (x, graph) pair into a padded store (rows [0, n)
     occupied, nothing tombstoned, epoch 0). ``qx``: optional (n, ·) codes
-    from the same encode the builder used — padded alongside x."""
+    from the same encode the builder used — padded alongside x.
+    ``labels``: optional (n,) uint32 label words (the store holds none
+    when None)."""
     n = x.shape[0]
     if g.n != n:
         raise ValueError(
@@ -139,6 +158,12 @@ def from_built(x: jnp.ndarray, g: G.Graph,
     if qx is not None and qx.codes.shape[0] != n:
         raise ValueError(
             f"qx holds {qx.codes.shape[0]} code rows but the corpus has {n}")
+    if labels is not None:
+        labels = jnp.asarray(labels).astype(jnp.uint32)
+    if labels is not None and labels.shape != (n,):
+        raise ValueError(
+            f"labels has shape {labels.shape} but the corpus has {n} rows: "
+            "pass one label word per row")
     cap = next_capacity(n if capacity is None else max(capacity, n))
     return Store(
         x=jnp.pad(x.astype(jnp.float32), ((0, cap - n), (0, 0))),
@@ -147,6 +172,7 @@ def from_built(x: jnp.ndarray, g: G.Graph,
         tombstone=jnp.zeros((cap,), bool),
         epoch=jnp.int32(0),
         qx=_pad_codes(qx, cap - n),
+        labels=_pad_labels(labels, cap - n),
     )
 
 
@@ -167,6 +193,7 @@ def grow(store: Store, min_capacity: int) -> Store:
         epoch=store.epoch,
         qx=_pad_codes(store.qx, pad),
         remap=store.remap,
+        labels=_pad_labels(store.labels, pad),
     )
 
 
@@ -206,6 +233,10 @@ def compact(store: Store) -> tuple[Store, np.ndarray]:
             store.qx._replace(
                 codes=jnp.asarray(np.asarray(store.qx.codes)[old_ids])),
             cap2 - n_new)
+    labels2 = None
+    if store.labels is not None:
+        labels2 = _pad_labels(
+            jnp.asarray(np.asarray(store.labels)[old_ids]), cap2 - n_new)
     new = Store(
         x=jnp.pad(jnp.asarray(np.asarray(store.x)[old_ids]),
                   ((0, cap2 - n_new), (0, 0))),
@@ -215,6 +246,7 @@ def compact(store: Store) -> tuple[Store, np.ndarray]:
         epoch=store.epoch + 1,
         qx=qx2,
         remap=jnp.asarray(remap),
+        labels=labels2,
     )
     return new, remap
 

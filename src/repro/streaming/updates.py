@@ -71,7 +71,7 @@ from repro.core import graph as G
 from repro.core import rnn_descent as rd
 from repro.core import search as S
 from repro.core import shard
-from repro.streaming.store import Store, active_mask, free_count
+from repro.streaming.store import Store, active_mask, free_count, row_labels
 
 NEW = G.NEW
 
@@ -302,8 +302,10 @@ def _graft(x, g: G.Graph, occupied, new_x, slots, cand_ids, cand_d,
 
 
 def insert(store: Store, new_x, cfg: StreamingConfig,
-           mesh=None) -> tuple[Store, np.ndarray]:
+           mesh=None, labels=None) -> tuple[Store, np.ndarray]:
     """Insert a batch of vectors; returns ``(new_store, row_ids)``.
+    ``labels``: optional (B,) uint32 label words of the new rows (0 when
+    None; the store gains a label array the first time labels are given).
 
     The store must have ``free_count(store) >= len(new_x)`` — capacity
     growth is the :class:`repro.streaming.index.StreamingANN` layer's job
@@ -318,6 +320,11 @@ def insert(store: Store, new_x, cfg: StreamingConfig,
         raise ValueError(
             f"store has {free_count(store)} free rows < batch {b}: grow the "
             "store first (StreamingANN.insert does this automatically)")
+    if labels is not None:
+        labels = jnp.asarray(labels).astype(jnp.uint32)
+    if labels is not None and labels.shape != (b,):
+        raise ValueError(
+            f"labels has shape {labels.shape} but the batch has {b} rows")
     slots = np.flatnonzero(~np.asarray(store.occupied))[:b].astype(np.int32)
 
     active = active_mask(store)
@@ -342,8 +349,14 @@ def insert(store: Store, new_x, cfg: StreamingConfig,
         qx2 = qx2._replace(
             codes=qx2.codes.at[jnp.asarray(slots)].set(
                 encode_rows(new_x, qx2)))
+    labels2 = store.labels
+    if labels is not None:
+        labels2 = row_labels(store).at[jnp.asarray(slots)].set(labels)
+    elif labels2 is not None:
+        labels2 = labels2.at[jnp.asarray(slots)].set(0)
     return Store(x=x2, graph=g2, occupied=occ2, tombstone=store.tombstone,
-                 epoch=store.epoch + 1, qx=qx2, remap=store.remap), slots
+                 epoch=store.epoch + 1, qx=qx2, remap=store.remap,
+                 labels=labels2), slots
 
 
 # ------------------------------------------------------------------- delete
@@ -450,6 +463,5 @@ def delete(store: Store, ids, cfg: StreamingConfig, mesh=None) -> Store:
 
     g2 = _repair(store.x, store.graph, tomb_new, jnp.asarray(a_idx), cfg,
                  mesh)
-    return Store(x=store.x, graph=g2, occupied=store.occupied,
-                 tombstone=tomb_new, epoch=store.epoch + 1, qx=store.qx,
-                 remap=store.remap)
+    return store._replace(graph=g2, tombstone=tomb_new,
+                          epoch=store.epoch + 1)

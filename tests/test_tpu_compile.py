@@ -148,3 +148,33 @@ def test_search_visited_table_stays_in_place(one_chip):
              and "slice_sizes={1,128}" in line
              and line.lstrip().split(" = ")[1].startswith("s32[")]
     assert probe
+
+
+def test_filtered_search_compiles_with_table_in_place(one_chip):
+    """The filtered beam (label words, one mask a query) at the
+    sift1m-attr12 cell's search (256-lane tile, L=64, k=64, max_iters=2048,
+    131,072 slots a lane: a 33,554,432-entry table a tile) compiles for the
+    chip, with the visited table written by row in place, as in the
+    unfiltered beam."""
+    n, d, cap, b = 4096, 128, 128, 256
+    cfg = S.SearchConfig(l=64, k=64, max_iters=2048, topk=10,
+                         visited="hashed", slots=131_072)
+    table = b * S.resolve_slots(cfg)
+    assert table == 33_554_432
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    g = G.Graph(sds((n, cap), jnp.int32), sds((n, cap), jnp.float32),
+                sds((n, cap), jnp.uint8))
+    hlo = S._search_tiled_jit.lower(
+        sds((n, d), jnp.float32), g, sds((b, d), jnp.float32),
+        sds((), jnp.int32), cfg, b, None, sds((n,), jnp.bool_), None,
+        "queries", True, None, sds((n,), jnp.uint32),
+        sds((b,), jnp.uint32)).compile().as_text()
+    ops = list(_ops(hlo))
+    moved = [line for op, sizes, line in ops
+             if op in ("copy", "copy-start", "slice-start", "reshape")
+             and table in sizes]
+    assert not moved, moved[:3]
+    assert any(op == "scatter" and table in sizes for op, sizes, _ in ops)
